@@ -6,14 +6,15 @@ import json
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import dataclasses  # noqa: E402
 
 import jax  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.config import TrainConfig, get_arch, reduced  # noqa: E402
 from repro.models.transformer import ModelCtx  # noqa: E402
 from repro.optimizer import adamw  # noqa: E402
@@ -25,7 +26,7 @@ cfg = dataclasses.replace(reduced(get_arch("recllm-base")),
                           vocab_size=ds.n_items + 3, vocab_pad_to=32,
                           dtype="float32")
 ctx = ModelCtx(attn_chunk=8)
-mesh = compat.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 STEPS = 50
 
 

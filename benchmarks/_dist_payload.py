@@ -30,6 +30,7 @@ os.environ["XLA_FLAGS"] = (
     f" --xla_dump_to={_DUMP}"
     " --xla_dump_hlo_pass_re=all-reduce-promotion"
     " --xla_dump_large_constants=false")
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import dataclasses  # noqa: E402
 import glob  # noqa: E402
@@ -48,17 +49,17 @@ from repro.models import transformer as tf  # noqa: E402
 from repro.optimizer import adamw  # noqa: E402
 from repro.runtime import trainer  # noqa: E402
 from repro.data import pipeline  # noqa: E402
+from repro.launch.mesh import make_host_mesh  # noqa: E402
 
 
 def make_mesh(scheme, n):
-    from repro import compat
     if scheme == "baseline":
-        return compat.make_mesh((1, 1), ("data", "model"))
+        return make_host_mesh()
     if scheme == "dp":
-        return compat.make_mesh((n, 1), ("data", "model"))
+        return make_host_mesh(data=n, model=1)
     if scheme == "mp":
-        return compat.make_mesh((1, n), ("data", "model"))
-    return compat.make_mesh((n // 2, 2), ("data", "model"))
+        return make_host_mesh(data=1, model=n)
+    return make_host_mesh(data=n // 2, model=2)
 
 
 cfg = dataclasses.replace(

@@ -30,6 +30,7 @@ args = ap.parse_args()
 _DP, _MP = (int(x) for x in args.mesh.split(","))
 os.environ["XLA_FLAGS"] = (
     f"--xla_force_host_platform_device_count={_DP * _MP}")
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import time  # noqa: E402
 
@@ -37,17 +38,17 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.analysis import hlo_cost  # noqa: E402
 from repro.config import (DCI_BW_PER_LINK, HBM_BW, ICI_BW_PER_LINK,  # noqa: E402
                           PEAK_FLOPS_BF16)
 from repro.embeddings import (EmbedSpec, make_plan, named_sharding,  # noqa: E402
                               plan_summary, pspec, shard_bytes,
                               sharded_lookup_body, sparse_row_sync)
+from repro.launch.mesh import make_host_mesh  # noqa: E402
 
-mesh = compat.make_mesh((_DP, _MP), ("data", "model"))
+mesh = make_host_mesh(data=_DP, model=_MP)
 spec = EmbedSpec("bench", rows=args.rows, dim=args.dim)
 plan = make_plan(args.plan)
 mesh_shape = dict(mesh.shape)
@@ -91,7 +92,7 @@ step = jax.jit(
     shard_map(body, mesh=mesh,
               in_specs=(tspec, P("data"), P("data")),
               out_specs=(tspec, P()),
-              check_rep=False),
+              check_vma=False),
     donate_argnums=(0,))
 
 table = jax.device_put(jnp.asarray(table0), named_sharding(mesh, plan))

@@ -30,23 +30,24 @@ args = ap.parse_args()
 _DP, _MP = (int(x) for x in args.mesh.split(","))
 os.environ["XLA_FLAGS"] = (
     f"--xla_force_host_platform_device_count={_DP * _MP}")
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import dataclasses  # noqa: E402
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.config import get_arch, reduced  # noqa: E402
 from repro.embeddings import EmbedSpec, make_plan  # noqa: E402
 from repro.models import transformer as tf  # noqa: E402
 from repro.serving import (CFHead, EngineConfig, ServingEngine,  # noqa: E402
                            TrafficConfig, cf_lookup_bytes, generate)
 from repro.serving.engine import make_backend  # noqa: E402
+from repro.launch.mesh import make_host_mesh  # noqa: E402
 
 cfg = dataclasses.replace(reduced(get_arch("olmo-1b")), dtype="float32")
 params = tf.init_params(jax.random.PRNGKey(0), cfg)
-mesh = compat.make_mesh((_DP, _MP), ("data", "model"))
+mesh = make_host_mesh(data=_DP, model=_MP)
 mesh_shape = dict(mesh.shape)
 
 reqs = generate(TrafficConfig(

@@ -24,6 +24,7 @@ args = ap.parse_args()
 
 os.environ["XLA_FLAGS"] = (
     f"--xla_force_host_platform_device_count={args.devices}")
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import dataclasses  # noqa: E402
 import time  # noqa: E402

@@ -9,14 +9,15 @@ per-step wire bytes each scheme puts on the interconnect.
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import dataclasses  # noqa: E402
 
 import jax  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat
 from repro.config import TrainConfig, get_arch, reduced  # noqa: E402
 from repro.data import pipeline  # noqa: E402
 from repro.models import transformer as tf  # noqa: E402
@@ -29,7 +30,8 @@ def main():
     cfg = dataclasses.replace(reduced(get_arch("recllm-base")),
                               dtype="float32")
     ctx = ModelCtx(attn_chunk=8)
-    mesh = compat.make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     tcfg = TrainConfig(steps=30, learning_rate=3e-3, warmup_steps=3,
                        checkpoint_every=0)
 

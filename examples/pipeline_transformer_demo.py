@@ -9,14 +9,15 @@ Verifies pipelined loss == serial loss, then trains a few steps.
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import dataclasses  # noqa: E402
 
 import jax  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat
 from repro.config import get_arch, reduced  # noqa: E402
 from repro.core import load_balance, pipeline  # noqa: E402
 from repro.core.hybrid import layer_flops  # noqa: E402
@@ -64,7 +65,7 @@ def main():
         logits = L.lm_logits(cfg, {**lp, "embed": lp["embed"]}, h)
         return L.cross_entropy_loss(logits, tgt)
 
-    mesh = compat.make_mesh((N_STAGES,), ("stage",))
+    mesh = jax.make_mesh((N_STAGES,), ("stage",), axis_types=(AxisType.Auto,))
     loss_fn = pipeline.make_pipeline_loss(stage_fn, last_fn, mesh,
                                           N_STAGES, N_MICRO)
 

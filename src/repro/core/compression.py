@@ -59,9 +59,14 @@ def onebit_sync(grads, residual: jnp.ndarray, *, axis: str = "data",
     # exchange compressed payloads (uint8 + per-block scales on the wire)
     packed_all = jax.lax.all_gather(packed, axis)            # (P, N/8) u8
     scales_all = jax.lax.all_gather(scales, axis)            # (P, nb) f32
-    deq = jax.vmap(lambda pk, sc: ops.onebit_dequantize(pk, sc, block,
-                                                        impl=impl))
-    g_hat = jnp.mean(deq(packed_all, scales_all), axis=0)
+    # accumulate one dequantized peer at a time: a (P, N) f32 stack of
+    # them would cost P dense gradients of device memory
+    g_hat = ops.onebit_dequantize(packed_all[0], scales_all[0], block,
+                                  impl=impl)
+    for p in range(1, packed_all.shape[0]):
+        g_hat = g_hat + ops.onebit_dequantize(packed_all[p], scales_all[p],
+                                              block, impl=impl)
+    g_hat = g_hat / packed_all.shape[0]
     n = flat.shape[0] - npad
     return _unflatten(g_hat[:n], meta), new_residual
 
@@ -83,13 +88,17 @@ def topk_sync(grads, residual: jnp.ndarray, *, axis: str = "data",
     vals = jnp.take_along_axis(kept2d, idx, axis=-1)         # signed values
 
     def scatter(v, i):
-        return jnp.zeros((nb, block), jnp.float32) \
-            .at[jnp.arange(nb)[:, None], i].add(v)
+        rows = jnp.broadcast_to(
+            jnp.arange(nb)[(None,) * (i.ndim - 2) + (slice(None), None)],
+            i.shape)
+        return jnp.zeros((nb, block), jnp.float32).at[rows, i].add(v)
 
     new_residual = flat - scatter(vals, idx).reshape(-1)
     vals_all = jax.lax.all_gather(vals, axis)                # (P, nb, k)
     idx_all = jax.lax.all_gather(idx, axis)
-    g_hat = jnp.mean(jax.vmap(scatter)(vals_all, idx_all), axis=0).reshape(-1)
+    # every peer's (values, indices) land in ONE dense buffer: a vmapped
+    # scatter would hold P dense gradients at once
+    g_hat = (scatter(vals_all, idx_all) / vals_all.shape[0]).reshape(-1)
     n = flat.shape[0] - npad
     return _unflatten(g_hat[:n], meta), new_residual
 

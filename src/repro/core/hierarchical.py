@@ -19,9 +19,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
-from repro import compat
 
 
 def _pad_to(x: jnp.ndarray, mult: int) -> Tuple[jnp.ndarray, int]:
@@ -43,14 +42,14 @@ def hierarchical_allreduce_mean(g: jnp.ndarray, intra_axis: str = "data",
     """reduce-scatter(intra) -> all-reduce(inter) -> all-gather(intra)."""
     shape = g.shape
     flat = g.reshape(-1)
-    n_intra = compat.axis_size(intra_axis)
+    n_intra = jax.lax.axis_size(intra_axis)
     flat, pad = _pad_to(flat, n_intra)
     shard = jax.lax.psum_scatter(flat, intra_axis, scatter_dimension=0,
                                  tiled=True)
     total = n_intra
     if inter_axis is not None:
         shard = jax.lax.psum(shard, inter_axis)
-        total *= compat.axis_size(inter_axis)
+        total *= jax.lax.axis_size(inter_axis)
     out = jax.lax.all_gather(shard, intra_axis, axis=0, tiled=True)
     if pad:
         out = out[:-pad]

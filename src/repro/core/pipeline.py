@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 SCHEDULES = ("gpipe", "1f1b")
 
@@ -91,7 +91,7 @@ def gpipe(stage_fn: Callable, mesh: Mesh, n_stages: int, n_micro: int,
         inner, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
 
 def make_pipeline_loss(stage_fn: Callable, last_fn: Callable, mesh: Mesh,
@@ -304,7 +304,7 @@ def make_pipeline_value_and_grad(stage_fn: Callable, last_fn: Callable,
         body, mesh=mesh,
         in_specs=(P(stage_axis), P(), P(), P(), P()),
         out_specs=(P(), P(stage_axis), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def vag(stage_params, last_params, x_micro, tgt_micro, mask_micro):
         loss, g_stage, g_last, g_x = sm(stage_params, last_params, x_micro,

@@ -30,7 +30,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.embeddings.table import EmbedPlan, EmbedSpec, pspec
 from repro.kernels import ops
@@ -118,7 +118,7 @@ def make_sharded_lookup(mesh: Mesh, spec: EmbedSpec, plan: EmbedPlan,
     fn = shard_map(partial(sharded_lookup_body, plan=plan), mesh=mesh,
                    in_specs=(pspec(plan), P(dp_axis)),
                    out_specs=P(dp_axis, None),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)
 
 

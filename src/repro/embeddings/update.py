@@ -30,7 +30,6 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.kernels import ops
 
 
@@ -104,7 +103,7 @@ def sparse_row_sync(dense_grad: jnp.ndarray, ids: jnp.ndarray,
     for ax in axes:
         u = jax.lax.all_gather(u, ax, axis=0, tiled=True)
         rows = jax.lax.all_gather(rows, ax, axis=0, tiled=True)
-        n_ranks *= compat.axis_size(ax)
+        n_ranks *= jax.lax.axis_size(ax)
     return scatter_rows(u, rows, v) / n_ranks
 
 

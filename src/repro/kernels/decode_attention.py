@@ -3,7 +3,7 @@
 One decode step attends a single query token per sequence against that
 sequence's resident KV cache.  The dense XLA path streams the **entire
 padded** cache ``(B, S_max, Hk, D)`` every step; this kernel streams only
-the live prefix.  Grid is ``(B, Hk, S/block_k)`` with the KV axis innermost
+the live prefix.  Grid is ``(B, S/block_k)`` with the KV axis innermost
 ("arbitrary"); the per-slot ``lengths`` vector is **scalar-prefetched** so
 
 * the KV BlockSpec index maps clamp every out-of-range block index onto the
@@ -13,11 +13,14 @@ the live prefix.  Grid is ``(B, Hk, S/block_k)`` with the KV axis innermost
 * a ``pl.when`` guard skips the online-softmax update for dead blocks, so
   the clamped (re-visited) block is never double-counted.
 
-GQA: q is reshaped to ``(B, Hk, G, D)`` and each grid cell computes all G
-query heads of one KV head against one KV block — repeated KV heads are
-never materialized.  Running max / sum / accumulator live in VMEM scratch
-across KV iterations (same online-softmax recurrence as the prefill flash
-kernel in :mod:`repro.kernels.flash_attention`).
+A KV tile is ``(1, block_k, Hk, D)`` — every KV head of one block, since
+the TPU's tiling takes a head dim only whole — and each grid cell loops
+over the heads, reading head ``h``'s ``(block_k, D)`` rows from the tile.
+GQA: q is reshaped to ``(B, Hk, G, D)`` and head ``h`` computes its G query
+heads against its rows — repeated KV heads are never materialized.
+Running max / sum / accumulator live in VMEM scratch, one ``(rows, .)``
+plane per KV head, across KV iterations (same online-softmax recurrence as
+the prefill flash kernel in :mod:`repro.kernels.flash_attention`).
 
 Three fused variants share the one kernel body:
 
@@ -56,7 +59,7 @@ inside the live range).
 query row to ``Sq = k`` draft rows per slot, folded into the kernel's row
 axis: q ``(B, Sq, H, D)`` becomes ``(B, Hk, Sq*G_pad, D)`` so draft row
 ``j`` of KV head ``h`` occupies kernel rows ``[j*G_pad, (j+1)*G_pad)`` and
-one grid cell still computes every row of one KV head against one KV
+one grid cell still computes every row of every KV head against one KV
 block.  A second scalar-prefetched vector ``q_lens`` (B,) carries the live
 draft length per slot — speculation is ragged under continuous batching —
 and the in-kernel masks become per-row: row ``j`` attends with *effective
@@ -81,10 +84,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro import compat
-
-_CompilerParams = compat.pallas_compiler_params()
 
 NEG_INF = -1e30
 LANES = 128
@@ -121,9 +120,10 @@ def _decode_kernel(lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
                    ring: bool, block_k: int, n_kv: int, S: int, g_pad: int,
                    quant: bool = False, ks_ref=None, vs_ref=None):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
     length = lens_ref[b]
     q_len = qlens_ref[b]
+    n_heads, rows = q_ref.shape[1], q_ref.shape[2]          # rows: Sq*g_pad
 
     @pl.when(ki == 0)
     def _init():
@@ -138,15 +138,6 @@ def _decode_kernel(lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (rows, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (block_k, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if quant:                                            # fold k scales
-            s = s * ks_ref[0, 0][None, :]
-        s = s * scale                                        # (rows, bk)
-
-        rows = q.shape[0]                                    # Sq * g_pad
         row_j = jax.lax.broadcasted_iota(                    # draft index
             jnp.int32, (rows, block_k), 0) // g_pad
         pos_k = ki * block_k + jax.lax.broadcasted_iota(
@@ -160,34 +151,44 @@ def _decode_kernel(lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
             if window > 0:
                 mask &= pos_k > eff - 1 - window
         mask &= row_j < q_len                        # ragged draft padding
-        s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[:, 0]                                 # (rows,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, 0] * corr + jnp.sum(p, axis=-1)
-        if quant:                                            # fold v scales
-            p = p * vs_ref[0, 0][None, :]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (block_k, D)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + pv
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        # the KV tile holds every head of block ``ki``: one strided read
+        # per head, the same online-softmax update per head
+        for h in range(n_heads):
+            q = q_ref[0, h].astype(jnp.float32)              # (rows, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)        # (block_k, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if quant:                                        # fold k scales
+                s = s * ks_ref[0, pl.ds(h, 1), :]
+            s = jnp.where(mask, s * scale, NEG_INF)          # (rows, bk)
+
+            m_prev = m_scr[h][:, :1]                         # (rows, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_scr[h][:, :1] * corr + jnp.sum(p, axis=-1,
+                                                     keepdims=True)
+            if quant:                                        # fold v scales
+                p = p * vs_ref[0, pl.ds(h, 1), :]
+            v = v_ref[0, :, h, :].astype(jnp.float32)        # (block_k, D)
+            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_scr[h] = acc_scr[h] * corr + pv
+            m_scr[h] = jnp.broadcast_to(m_new, (rows, LANES))
+            l_scr[h] = jnp.broadcast_to(l_new, (rows, LANES))
 
     @pl.when(ki == n_kv - 1)
     def _done():
-        l = jnp.maximum(l_scr[:, 0], 1e-30)            # dead rows -> 0/1
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[...][:, :, :1], 1e-30)         # dead rows -> 0/1
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def _prep_q(q, Hk: int):
     """(B, Sq, H, D) -> padded (B, Hk, Sq*G_pad, D); returns
     (qg, Sq, G, G_pad).  Draft row ``j`` lands on kernel rows
     ``[j*G_pad, (j+1)*G_pad)`` — the row axis folds drafts and query-head
-    groups so one grid cell computes every draft row of one KV head."""
+    groups so one head's pass computes every draft row of that KV head."""
     B, Sq, H, D = q.shape
     G = H // Hk
     qg = q.reshape(B, Sq, Hk, G, D).transpose(0, 2, 1, 3, 4)
@@ -218,6 +219,39 @@ def _pad_kv_len(x, block_k: int):
     return x
 
 
+def _run(kernel, prefetch, qg, kv_args, kv_specs, *, n_kv: int,
+         interpret: bool):
+    """One ``pallas_call`` over grid ``(B, n_kv)``: every KV head of one
+    slot's block ``ki`` per grid step.  KV tiles are ``(1, bk, Hk, D)`` —
+    the head and feature dims whole, as the TPU's (8, 128) tiling
+    requires — and q / out / scratch carry all ``Hk`` heads of the slot."""
+    B, Hk, rows, D = qg.shape
+    n_pf = len(prefetch)
+
+    def slot_map(b, ki, *_):
+        return (b, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_pf,
+        grid=(B, n_kv),
+        in_specs=[pl.BlockSpec((1, Hk, rows, D), slot_map)] + kv_specs,
+        out_specs=pl.BlockSpec((1, Hk, rows, D), slot_map),
+        scratch_shapes=[
+            pltpu.VMEM((Hk, rows, LANES), jnp.float32),
+            pltpu.VMEM((Hk, rows, LANES), jnp.float32),
+            pltpu.VMEM((Hk, rows, D), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), qg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, qg, *kv_args)
+
+
 def flash_decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
                            ring: bool = False, softmax_scale=None,
                            block_k: int = 128, interpret: bool = False,
@@ -229,52 +263,26 @@ def flash_decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
     masking variant; draft row ``j`` attends with effective length
     ``lengths + j`` (see module docstring)."""
     B, Sq, H, D = q.shape
-    S = k_cache.shape[1]
-    Hk = k_cache.shape[2]
+    S, Hk = k_cache.shape[1], k_cache.shape[2]
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     block_k = min(block_k, S)
     qg, Sq, G, G_pad = _prep_q(q, Hk)
     k_cache = _pad_kv_len(k_cache, block_k)
     v_cache = _pad_kv_len(v_cache, block_k)
-    S_pad = k_cache.shape[1]
-    n_kv = S_pad // block_k
-    lengths = lengths.astype(jnp.int32)
-    q_lens = _q_lens_or_full(q_lens, B, Sq)
+    n_kv = k_cache.shape[1] // block_k
 
-    def kv_map(b, h, ki, lens, qlens):
+    def kv_map(b, ki, lens, qlens):
         lo, hi = _live_block_bounds(lens[b], block_k, S, window, ring,
                                     qlens[b])
-        return (b, jnp.clip(ki, lo, hi), h, 0)
+        return (b, jnp.clip(ki, lo, hi), 0, 0)
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, ring=ring,
         block_k=block_k, n_kv=n_kv, S=S, g_pad=G_pad)
-    rows = Sq * G_pad
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hk, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, D),
-                         lambda b, h, ki, lens, qlens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), kv_map),
-            pl.BlockSpec((1, block_k, 1, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, D),
-                               lambda b, h, ki, lens, qlens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(lengths, q_lens, qg, k_cache, v_cache)
+    kv_spec = pl.BlockSpec((1, block_k, Hk, D), kv_map)
+    out = _run(kernel, (lengths.astype(jnp.int32), _q_lens_or_full(
+        q_lens, B, Sq)), qg, (k_cache, v_cache), [kv_spec, kv_spec],
+        n_kv=n_kv, interpret=interpret)
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)
 
 
@@ -287,8 +295,7 @@ def flash_decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths, *,
     ``q_lens`` enables k-row speculative verification as in
     :func:`flash_decode_attention`."""
     B, Sq, H, D = q.shape
-    S = k_q.shape[1]
-    Hk = k_q.shape[2]
+    S, Hk = k_q.shape[1], k_q.shape[2]
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     block_k = min(block_k, S)
     qg, Sq, G, G_pad = _prep_q(q, Hk)
@@ -297,18 +304,14 @@ def flash_decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths, *,
     # scales travel as (B, Hk, S): lane-major along the blocked axis
     k_s = _pad_kv_len(k_s, block_k).transpose(0, 2, 1)
     v_s = _pad_kv_len(v_s, block_k).transpose(0, 2, 1)
-    S_pad = k_q.shape[1]
-    n_kv = S_pad // block_k
-    lengths = lengths.astype(jnp.int32)
-    q_lens = _q_lens_or_full(q_lens, B, Sq)
+    n_kv = k_q.shape[1] // block_k
 
-    def kv_map(b, h, ki, lens, qlens):
+    def kv_map(b, ki, lens, qlens):
         lo, hi = _live_block_bounds(lens[b], block_k, S, 0, False, qlens[b])
-        return (b, jnp.clip(ki, lo, hi), h, 0)
+        return (b, jnp.clip(ki, lo, hi), 0, 0)
 
-    def scale_map(b, h, ki, lens, qlens):
-        lo, hi = _live_block_bounds(lens[b], block_k, S, 0, False, qlens[b])
-        return (b, h, jnp.clip(ki, lo, hi))
+    def scale_map(b, ki, lens, qlens):
+        return (b, 0, kv_map(b, ki, lens, qlens)[1])
 
     def kernel(lens_ref, qlens_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                o_ref, m_scr, l_scr, acc_scr):
@@ -318,34 +321,13 @@ def flash_decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths, *,
                        g_pad=G_pad, quant=True, ks_ref=ks_ref,
                        vs_ref=vs_ref)
 
-    rows = Sq * G_pad
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hk, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, D),
-                         lambda b, h, ki, lens, qlens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), kv_map),
-            pl.BlockSpec((1, 1, block_k), scale_map),
-            pl.BlockSpec((1, block_k, 1, D), kv_map),
-            pl.BlockSpec((1, 1, block_k), scale_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, D),
-                               lambda b, h, ki, lens, qlens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(lengths, q_lens, qg, k_q, k_s, v_q, v_s)
+    kv_spec = pl.BlockSpec((1, block_k, Hk, D), kv_map)
+    # (1, Hk, bk) scale tiles: Hk is the whole dim; bk a lane multiple or
+    # the whole (padded) length
+    s_spec = pl.BlockSpec((1, Hk, block_k), scale_map)
+    out = _run(kernel, (lengths.astype(jnp.int32), _q_lens_or_full(
+        q_lens, B, Sq)), qg, (k_q, k_s, v_q, v_s),
+        [kv_spec, s_spec, kv_spec, s_spec], n_kv=n_kv, interpret=interpret)
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)
 
 
@@ -366,13 +348,10 @@ def flash_decode_attention_paged(q, k_pool, v_pool, block_tables, lengths, *,
     S = nb * bs                              # virtual position space
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     qg, Sq, G, G_pad = _prep_q(q, Hk)
-    lengths = lengths.astype(jnp.int32)
-    q_lens = _q_lens_or_full(q_lens, B, Sq)
-    block_tables = block_tables.astype(jnp.int32)
 
-    def kv_map(b, h, ki, lens, qlens, tables):
+    def kv_map(b, ki, lens, qlens, tables):
         lo, hi = _live_block_bounds(lens[b], bs, S, window, ring, qlens[b])
-        return (tables[b, jnp.clip(ki, lo, hi)], 0, h, 0)
+        return (tables[b, jnp.clip(ki, lo, hi)], 0, 0, 0)
 
     kernel_body = functools.partial(
         _decode_kernel, scale=scale, window=window, ring=ring,
@@ -383,33 +362,12 @@ def flash_decode_attention_paged(q, k_pool, v_pool, block_tables, lengths, *,
         kernel_body(lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
                     m_scr, l_scr, acc_scr)
 
-    rows = Sq * G_pad
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hk, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, D),
-                         lambda b, h, ki, lens, qlens, tables: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, rows, D),
-            lambda b, h, ki, lens, qlens, tables: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(lengths, q_lens, block_tables, qg, k_pool, v_pool)
+    kv_spec = pl.BlockSpec((1, bs, Hk, D), kv_map)
+    out = _run(kernel, (lengths.astype(jnp.int32),
+                        _q_lens_or_full(q_lens, B, Sq),
+                        block_tables.astype(jnp.int32)),
+               qg, (k_pool, v_pool), [kv_spec, kv_spec], n_kv=nb,
+               interpret=interpret)
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)
 
 
@@ -428,20 +386,16 @@ def flash_decode_attention_paged_quant(q, k_q_pool, k_s_pool, v_q_pool,
     S = nb * bs
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     qg, Sq, G, G_pad = _prep_q(q, Hk)
-    lengths = lengths.astype(jnp.int32)
-    q_lens = _q_lens_or_full(q_lens, B, Sq)
-    block_tables = block_tables.astype(jnp.int32)
-    # scales travel as (N, Hk, bs): lane-major along the blocked axis
+    # scales travel as (N, Hk, bs): a (1, Hk, bs) tile is two whole dims
     k_s_pool = k_s_pool.transpose(0, 2, 1)
     v_s_pool = v_s_pool.transpose(0, 2, 1)
 
-    def kv_map(b, h, ki, lens, qlens, tables):
+    def kv_map(b, ki, lens, qlens, tables):
         lo, hi = _live_block_bounds(lens[b], bs, S, 0, False, qlens[b])
-        return (tables[b, jnp.clip(ki, lo, hi)], 0, h, 0)
+        return (tables[b, jnp.clip(ki, lo, hi)], 0, 0, 0)
 
-    def scale_map(b, h, ki, lens, qlens, tables):
-        lo, hi = _live_block_bounds(lens[b], bs, S, 0, False, qlens[b])
-        return (tables[b, jnp.clip(ki, lo, hi)], h, 0)
+    def scale_map(b, ki, lens, qlens, tables):
+        return kv_map(b, ki, lens, qlens, tables)[:3]
 
     def kernel(lens_ref, qlens_ref, tables_ref, q_ref, kq_ref, ks_ref,
                vq_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr):
@@ -450,34 +404,12 @@ def flash_decode_attention_paged_quant(q, k_q_pool, k_s_pool, v_q_pool,
                        ring=False, block_k=bs, n_kv=nb, S=S, g_pad=G_pad,
                        quant=True, ks_ref=ks_ref, vs_ref=vs_ref)
 
-    rows = Sq * G_pad
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hk, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, D),
-                         lambda b, h, ki, lens, qlens, tables: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, 1, bs), scale_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, 1, bs), scale_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, rows, D),
-            lambda b, h, ki, lens, qlens, tables: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(lengths, q_lens, block_tables, qg, k_q_pool, k_s_pool, v_q_pool,
-      v_s_pool)
+    kv_spec = pl.BlockSpec((1, bs, Hk, D), kv_map)
+    s_spec = pl.BlockSpec((1, Hk, bs), scale_map)
+    out = _run(kernel, (lengths.astype(jnp.int32),
+                        _q_lens_or_full(q_lens, B, Sq),
+                        block_tables.astype(jnp.int32)),
+               qg, (k_q_pool, k_s_pool, v_q_pool, v_s_pool),
+               [kv_spec, s_spec, kv_spec, s_spec], n_kv=nb,
+               interpret=interpret)
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)

@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
-_CompilerParams = compat.pallas_compiler_params()
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -114,7 +110,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, softmax_scale=None,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -4,7 +4,9 @@ vectorizes on the VPU (the TPU analogue of a CUDA warp-ballot pack).
 
 Layout contract (matches ``ref.onebit_quantize``): the flat gradient of size
 N (N % 8 == 0) is viewed as (8, M) with M = N // 8; ``packed[j]`` holds the 8
-sign bits of column j; one f32 L1 scale per ``block`` columns.
+sign bits of column j; one f32 L1 scale per ``block`` columns.  Inside the
+kernels a scale travels as one lane-broadcast ``(1, 1, 128)`` tile, the
+smallest block the TPU's tiling accepts.
 """
 from __future__ import annotations
 
@@ -13,7 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+_LANES = 128
 
 
 def _quant_kernel(g_ref, packed_ref, scale_ref, *, block: int):
@@ -23,7 +26,7 @@ def _quant_kernel(g_ref, packed_ref, scale_ref, *, block: int):
     weights = jnp.left_shift(jnp.ones_like(w), w)          # 2^row
     packed = jnp.sum(bits * weights, axis=0)               # (block,) int32
     packed_ref[...] = packed[None, :].astype(jnp.uint8)
-    scale_ref[0, 0] = jnp.mean(jnp.abs(g))
+    scale_ref[...] = jnp.full(scale_ref.shape, jnp.mean(jnp.abs(g)))
 
 
 def _dequant_kernel(packed_ref, scale_ref, g_ref, *, block: int):
@@ -31,7 +34,7 @@ def _dequant_kernel(packed_ref, scale_ref, g_ref, *, block: int):
     j = jax.lax.broadcasted_iota(jnp.int32, (8, block), 0)
     bits = jnp.right_shift(jnp.broadcast_to(packed, (8, block)), j) & 1
     signs = 2.0 * bits.astype(jnp.float32) - 1.0
-    g_ref[...] = signs * scale_ref[0, 0]
+    g_ref[...] = signs * scale_ref[0][:, :1]
 
 
 def onebit_quantize(g2d: jnp.ndarray, block: int = 512, interpret=False):
@@ -45,15 +48,15 @@ def onebit_quantize(g2d: jnp.ndarray, block: int = 512, interpret=False):
         in_specs=[pl.BlockSpec((8, block), lambda i: (0, i))],
         out_specs=[
             pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, i), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, _LANES), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, M), jnp.uint8),
-            jax.ShapeDtypeStruct((1, nb), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(g2d)
-    return packed[0], scales[0]
+    return packed[0], scales[:, 0, 0]
 
 
 def onebit_dequantize(packed: jnp.ndarray, scales: jnp.ndarray,
@@ -66,10 +69,11 @@ def onebit_dequantize(packed: jnp.ndarray, scales: jnp.ndarray,
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, i), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, _LANES), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((8, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((8, M), jnp.float32),
         interpret=interpret,
-    )(packed[None, :], scales[None, :])
+    )(packed[None, :], jnp.broadcast_to(scales[:, None, None],
+                                        (nb, 1, _LANES)))
     return g

@@ -1,7 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On a CPU backend (this container) kernels run in ``interpret=True`` mode so
-they are validated end-to-end; on TPU they compile natively.  ``impl`` can
+On a CPU backend kernels run in ``interpret=True`` mode so they are
+validated end-to-end; on a TPU they compile natively (every kernel here is
+compiled for a v5e at real widths by ``tests/test_tpu_compile.py``, and
+``chip_smoke.py`` runs the decode kernels on the chip).  ``impl`` can
 force ``"ref"`` (pure-jnp oracle) — the default for *lowering* paths where a
 clean HLO matters (dry-run roofline) is chosen by the caller.
 """

@@ -14,34 +14,40 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_ROWS = 8
+
 
 def _kernel(x_ref, kept_ref, resid_ref, *, k: int):
-    x = x_ref[...]                                          # (1, block)
+    x = x_ref[...]                                       # (rows, block)
     a = jnp.abs(x)
 
     def body(_, carry):
         tmp, thr = carry
-        m = jnp.max(tmp)
+        m = jnp.max(tmp, axis=-1, keepdims=True)             # per row
         tmp = jnp.where(tmp >= m, -1.0, tmp)
         return tmp, m
 
-    _, t = jax.lax.fori_loop(0, k, body, (a, jnp.float32(jnp.inf)))
+    _, t = jax.lax.fori_loop(0, k, body,
+                             (a, jnp.full((x.shape[0], 1), jnp.inf)))
     kept = jnp.where(a >= t, x, 0.0)
     kept_ref[...] = kept
     resid_ref[...] = x - kept
 
 
 def topk_sparsify(x2d: jnp.ndarray, k: int, interpret=False):
-    """x2d: (nb, block) f32 -> (kept, residual) same shape."""
+    """x2d: (nb, block) f32 -> (kept, residual) same shape.  Each grid step
+    takes ``_ROWS`` blocks (one sublane tile; zero rows pad the tail)."""
     nb, block = x2d.shape
+    n_pad = nb + (-nb) % _ROWS
+    x2d = jnp.pad(x2d, ((0, n_pad - nb), (0, 0)))
+    spec = pl.BlockSpec((_ROWS, block), lambda i: (i, 0))
     kept, resid = pl.pallas_call(
         functools.partial(_kernel, k=k),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, block), lambda i: (i, 0)),
-                   pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, block), jnp.float32)],
+        grid=(n_pad // _ROWS,),
+        in_specs=[spec],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((n_pad, block), jnp.float32),
+                   jax.ShapeDtypeStruct((n_pad, block), jnp.float32)],
         interpret=interpret,
     )(x2d)
-    return kept, resid
+    return kept[:nb], resid[:nb]

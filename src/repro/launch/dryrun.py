@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines: jax locks the device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST come before any jax import: jax locks the platform and the device
+# count on first init.
 # The production dry-run needs 512 placeholder devices for the 2x16x16 mesh.
 
 # HLO dump (still before any jax import): the roofline reads the post-SPMD,

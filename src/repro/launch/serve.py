@@ -85,9 +85,10 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.cache_layout import CacheLayout
 from repro.config import get_arch, list_archs, reduced
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tf
 from repro.models.transformer import ModelCtx
 from repro.obs import MetricsRegistry, Tracer, write_trace
@@ -98,11 +99,30 @@ from repro.serving.engine import make_backend
 from repro.serving.metrics import format_report
 
 
-def run_engine(args) -> int:
+@dataclasses.dataclass
+class EngineRun:
+    """What one engine-mode run served: the workload, the measured
+    engine, its outputs {rid: tokens} and summary, and host wall seconds of
+    the warm-up run (it compiles every prefill bucket and the decode
+    step)."""
+    requests: list
+    engine: object
+    outputs: dict
+    summary: dict
+    warmup_s: float
+
+
+def init_params(cfg, seed: int):
+    """Random weights from ``seed``, built on the device in one program."""
+    return jax.jit(tf.init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+
+
+def run_engine(args) -> EngineRun:
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(reduced(cfg), dtype="float32")
-    params = tf.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_params(cfg, args.seed)
 
     defaults = TrafficConfig()
     tcfg = TrafficConfig(
@@ -155,7 +175,7 @@ def run_engine(args) -> int:
                 return None
             # trivial 1x1 mesh off-TPU: exercises the plan's shard_map
             # path; a real deployment hands in the training mesh
-            mesh = compat.make_mesh((1, 1), ("data", "model"))
+            mesh = make_host_mesh()
             return CFHead.build(
                 n_users=tcfg.n_users, n_items=cfg.vocab_size, cf_dim=16,
                 seed=args.seed, plan=args.cf_plan,
@@ -172,10 +192,12 @@ def run_engine(args) -> int:
             return ServingEngine(backend, ecfg, tracer=tracer,
                                  metrics=metrics, cf_head=mk_cf_head())
 
+        t0 = time.perf_counter()
         if not args.no_warmup:
             # compile every prefill bucket + the decode step outside the
             # measured run, as a resident production server would be
             mk_server().run(requests)
+        warmup_s = time.perf_counter() - t0
         # tracing is scoped to the measured run only, never the warmup
         tracer = Tracer() if args.trace_out else None
         metrics = MetricsRegistry() if args.trace_out else None
@@ -183,10 +205,17 @@ def run_engine(args) -> int:
     except ValueError as e:       # layout/family/spec_k mismatches
         raise SystemExit(str(e))
     outputs, records, summary = engine.run(requests)
+    if args.trace_out:
+        n = write_trace(args.trace_out, tracer, metrics)
+        print(f"trace: {n} events -> {args.trace_out} "
+              f"(open at https://ui.perfetto.dev)")
+    return EngineRun(requests, engine, outputs, summary, warmup_s)
 
+
+def print_report(args, summary) -> None:
     topo = (f"disagg {args.prefill_replicas}P+{args.decode_replicas}D "
             f"{args.router_policy} " if args.disagg else "")
-    title = (f"{cfg.name} {topo}{args.cache_layout} kv={args.kv} "
+    title = (f"{args.arch} {topo}{args.cache_layout} kv={args.kv} "
              f"refill={args.refill} "
              f"slots={args.slots} {args.process}@{args.rate:g}req/s")
     print(format_report(summary, title))
@@ -196,13 +225,8 @@ def run_engine(args) -> int:
               f"cache_rows={s['cache_rows']} (live {s['cache_rows_live']}) "
               f"hit_rate={s['hit_rate']:.3f} "
               f"({s['hits']} hits / {s['misses']} misses)")
-    if args.trace_out:
-        n = write_trace(args.trace_out, tracer, metrics)
-        print(f"trace: {n} events -> {args.trace_out} "
-              f"(open at https://ui.perfetto.dev)")
     if args.json:
         print(json.dumps(summary, indent=1))
-    return 0
 
 
 def run_raw(args) -> int:
@@ -211,7 +235,7 @@ def run_raw(args) -> int:
     if args.reduced:
         cfg = dataclasses.replace(reduced(cfg), dtype="float32")
     ctx = ModelCtx(attn_chunk=64, mamba_chunk=16, moe_group=64)
-    params = tf.init_params(jax.random.PRNGKey(0), cfg)
+    params = init_params(cfg, args.seed)
     cache = tf.init_cache(cfg, args.batch, args.max_len)
     if cfg.encoder_layers:
         frames = jnp.zeros((args.batch, cfg.encoder_frames, cfg.d_model),
@@ -235,12 +259,12 @@ def run_raw(args) -> int:
     jax.block_until_ready(logits)
     dt = time.perf_counter() - t0
     tps = args.batch * args.new_tokens / dt
-    print(f"{cfg.name}: {tps:.1f} tokens/s (host CPU), "
+    print(f"{cfg.name}: {tps:.1f} tokens/s ({jax.devices()[0].platform}), "
           f"{dt / args.new_tokens * 1e3:.1f} ms/step at batch {args.batch}")
     return 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true")
@@ -338,7 +362,9 @@ def main(argv=None) -> int:
                     help="sampling temperature (0 = greedy)")
     ap.add_argument("--top-k", type=int, default=0,
                     help="restrict sampling to the k best logits (0 = off)")
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights, the traffic and "
+                         "sampling")
     ap.add_argument("--no-warmup", action="store_true")
     ap.add_argument("--trace-out", default="",
                     help="write the measured run's span timeline + metrics "
@@ -348,10 +374,16 @@ def main(argv=None) -> int:
     # raw mode
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=32)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.mode == "raw":
         return run_raw(args)
-    return run_engine(args)
+    print_report(args, run_engine(args).summary)
+    return 0
 
 
 if __name__ == "__main__":

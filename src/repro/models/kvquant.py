@@ -110,13 +110,14 @@ def init_paged_quant_cache(cfg, n_slots: int, max_len: int, *,
         raise ValueError(f"max_len={max_len} not a multiple of "
                          f"block_size={block_size}")
     L, Hk, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    tbl = jnp.zeros((n_slots, max_len // block_size), jnp.int32)
+    nb = max_len // block_size
     return {
         "k_q": jnp.zeros((L, num_blocks, block_size, Hk, D), jnp.int8),
         "k_s": jnp.zeros((L, num_blocks, block_size, Hk), jnp.float32),
         "v_q": jnp.zeros((L, num_blocks, block_size, Hk, D), jnp.int8),
         "v_s": jnp.zeros((L, num_blocks, block_size, Hk), jnp.float32),
-        "block_table": tbl, "write_table": tbl,
+        "block_table": jnp.zeros((n_slots, nb), jnp.int32),
+        "write_table": jnp.zeros((n_slots, nb), jnp.int32),
         "len": jnp.zeros((n_slots,), jnp.int32),
     }
 

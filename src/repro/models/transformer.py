@@ -946,7 +946,7 @@ def make_stage_fn(cfg: ArchConfig, ctx: ModelCtx = ModelCtx(),
 
         def body(h, inp):
             blk, m = inp
-            m = jax.lax.stop_gradient(m)        # the pad mask is not a param
+            m = jax.lax.stop_gradient(m).astype(h.dtype)  # pad mask: no param
             a_out, _ = attn_apply(cfg_l, blk["attn"], f_in(h), positions,
                                   ctx)
             h = h + m * g_out(a_out)
@@ -1304,10 +1304,11 @@ def init_paged_slots(cfg: ArchConfig, n_slots: int, max_len: int, *,
     Hk, D = cfg.num_kv_heads, cfg.head_dim
     L = cfg.num_layers
     nb = max_len // block_size
-    tbl = jnp.zeros((n_slots, nb), jnp.int32)
+    # two tables, two buffers: a donated step must not see one buffer twice
     return {"k": jnp.zeros((L, num_blocks, block_size, Hk, D), dtype),
             "v": jnp.zeros((L, num_blocks, block_size, Hk, D), dtype),
-            "block_table": tbl, "write_table": tbl,
+            "block_table": jnp.zeros((n_slots, nb), jnp.int32),
+            "write_table": jnp.zeros((n_slots, nb), jnp.int32),
             "len": jnp.zeros((n_slots,), jnp.int32)}
 
 

@@ -23,7 +23,7 @@ from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.checkpoint import manager as ckpt
 from repro.config import ArchConfig, ParallelConfig, TrainConfig
@@ -235,7 +235,6 @@ def make_dp_train_step(loss_fn: Callable, mesh: Mesh, tcfg: TrainConfig,
                 if k not in embed_sync.id_fns}
         return emb, rest
 
-    from repro import compat
     world = math.prod(mesh.shape[a] for a in axes)
     tables = tuple(embed_sync.id_fns) if embed_sync else ()
     if zero_opt:
@@ -249,7 +248,7 @@ def make_dp_train_step(loss_fn: Callable, mesh: Mesh, tcfg: TrainConfig,
     def _flat_rank():
         r = jnp.zeros((), jnp.int32)
         for ax in axes:
-            r = r * compat.axis_size(ax) + jax.lax.axis_index(ax)
+            r = r * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         return r
 
     def inner(params, opt, residual, batch):
@@ -325,7 +324,7 @@ def make_dp_train_step(loss_fn: Callable, mesh: Mesh, tcfg: TrainConfig,
         inner, mesh=mesh,
         in_specs=(P(), opt_specs, dp_spec, dp_spec),
         out_specs=(P(), opt_specs, dp_spec, P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(inner_sm, donate_argnums=(0, 1, 2))
 
 
@@ -371,6 +370,29 @@ def pp_residual_size(cfg: ArchConfig, pp_params_shape, mesh,
             n += sum(l.size for l in jax.tree.leaves(pp_params_shape[key]))
     mult = 8 * scfg.block if scfg.mode == "onebit" else scfg.topk_block
     return n + ((-n) % mult)
+
+
+def pp_state_specs(cfg: ArchConfig, mesh: Mesh, pp_params_shape,
+                   scfg: DPSyncConfig = DPSyncConfig()):
+    """(param, optimizer, residual) PartitionSpecs of the pipelined step's
+    state: stage blocks over ``stage`` (+ Megatron dims over ``model``),
+    the head / embedding replicated, the compression residual one row per
+    (data, model, stage) rank.  Jit the state's init with these as
+    ``out_shardings`` to build it sharded from the start."""
+    stage_specs = sharding_lib.pp_stage_specs(cfg, pp_params_shape["stage"],
+                                              mesh)
+    tied = cfg.tie_embeddings
+    param_specs = {"stage": stage_specs,
+                   "last": jax.tree.map(lambda _: P(),
+                                        pp_params_shape["last"])}
+    tr_specs = {"stage": {"blocks": stage_specs["blocks"]},
+                "last": param_specs["last"]}
+    if not tied:
+        param_specs["embed"] = P()
+        tr_specs["embed"] = P()
+    opt_specs = {"m": tr_specs, "v": tr_specs, "master": tr_specs,
+                 "step": P()}
+    return param_specs, opt_specs, P(scfg.intra_axis, "model", "stage", None)
 
 
 def make_pp_train_step(cfg: ArchConfig, mesh: Mesh, tcfg: TrainConfig,
@@ -526,23 +548,13 @@ def make_pp_train_step(cfg: ArchConfig, mesh: Mesh, tcfg: TrainConfig,
             new_params["embed"] = new_tr["embed"]
         return new_params, new_opt, new_res, loss
 
-    param_specs = {"stage": stage_specs,
-                   "last": jax.tree.map(lambda _: P(),
-                                        pp_params_shape["last"])}
-    if not tied:
-        param_specs["embed"] = P()
-    tr_specs = {"stage": {"blocks": stage_specs["blocks"]},
-                "last": param_specs["last"]}
-    if not tied:
-        tr_specs["embed"] = P()
-    opt_specs = {"m": tr_specs, "v": tr_specs, "master": tr_specs,
-                 "step": P()}
-    res_spec = P(scfg.intra_axis, "model", "stage", None)
+    param_specs, opt_specs, res_spec = pp_state_specs(cfg, mesh,
+                                                      pp_params_shape, scfg)
     inner_sm = shard_map(
         inner, mesh=mesh,
         in_specs=(param_specs, opt_specs, res_spec, P(scfg.intra_axis)),
         out_specs=(param_specs, opt_specs, res_spec, P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(inner_sm, donate_argnums=(0, 1, 2))
 
 
@@ -730,6 +742,9 @@ class TrainResult:
     final_step: int
     losses: list
     throughput: float               # samples/sec (host wall clock)
+    # host wall seconds of each step, loss fetched (the first one includes
+    # compilation)
+    step_seconds: list = dataclasses.field(default_factory=list)
 
 
 def train_loop(state: Dict[str, Any], batches: Iterator, step_fn: Callable,
@@ -759,7 +774,7 @@ def train_loop(state: Dict[str, Any], batches: Iterator, step_fn: Callable,
     timeline (``launch/train.py --trace-out``).
     """
     tr = or_null(tracer)
-    losses = []
+    losses, step_seconds = [], []
     t0 = time.perf_counter()
     step = start_step
     n = 0
@@ -773,6 +788,7 @@ def train_loop(state: Dict[str, Any], batches: Iterator, step_fn: Callable,
                 if verbose:
                     print(f"step {step}: rebalanced "
                           f"(bounds {getattr(rebalance_fn, 'bounds', '?')})")
+        t_step = time.perf_counter()
         with tr.span("train_step", track="train", step=step) as sp:
             if "residual" in state:
                 state["params"], state["opt"], state["residual"], loss = \
@@ -783,6 +799,7 @@ def train_loop(state: Dict[str, Any], batches: Iterator, step_fn: Callable,
                 state["params"], state["opt"], metrics = step_fn(
                     state["params"], state["opt"], batch)
             losses.append(float(metrics["loss"]))
+            step_seconds.append(time.perf_counter() - t_step)
             if tr.enabled:
                 sp.args["loss"] = losses[-1]
         step += 1
@@ -806,7 +823,7 @@ def train_loop(state: Dict[str, Any], batches: Iterator, step_fn: Callable,
     dt = time.perf_counter() - t0
     tput = samples_per_batch * n / dt if dt > 0 else 0.0
     return TrainResult(steps_run=n, final_step=step, losses=losses,
-                       throughput=tput)
+                       throughput=tput, step_seconds=step_seconds)
 
 
 def resume_or_init(init_state: Dict[str, Any], tcfg: TrainConfig,

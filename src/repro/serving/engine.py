@@ -294,9 +294,9 @@ class SlotBackend:
             self.ctx = dataclasses.replace(self.ctx, decode_impl=decode_impl)
         # the slot state is consumed and replaced every call: donating it
         # lets XLA update the KV cache in place instead of allocating a
-        # fresh multi-MB copy per decode step (no-op on the CPU backend,
-        # which would only log a donation warning)
-        donate = () if jax.default_backend() == "cpu" else (1,)
+        # fresh multi-MB copy per decode step.  Every backend honours it,
+        # so a read of a donated state fails the same way on the CPU
+        donate = (1,)
         self._decode = jax.jit(self._decode_impl, donate_argnums=donate)
         # the patch grid is layout (shapes the traced position tensor):
         # static arg, one compile per distinct grid — like prompt buckets
@@ -790,9 +790,9 @@ class PagedSlots(_PagedBackendMixin, SlotBackend):
         state = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(template), pooled)
         state = dict(state)
-        tbl = jnp.zeros((n_slots, nb), jnp.int32)
-        state["block_table"] = tbl
-        state["write_table"] = tbl
+        # distinct buffers: the donated decode step must not alias them
+        state["block_table"] = jnp.zeros((n_slots, nb), jnp.int32)
+        state["write_table"] = jnp.zeros((n_slots, nb), jnp.int32)
         return state
 
     @staticmethod

@@ -6,6 +6,7 @@ the device count at first init, so the main pytest process — which must see
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"   # virtual host devices, never a chip
 
 import json  # noqa: E402
 import traceback  # noqa: E402
@@ -13,10 +14,12 @@ import traceback  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax.sharding import (AxisType, Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P)
+from jax import shard_map  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.mesh import make_host_mesh  # noqa: E402
+
 
 RESULTS = {}
 
@@ -35,11 +38,12 @@ def check(name):
 
 
 def pod_mesh():
-    return compat.make_mesh((2, 4), ("pod", "data"))
+    return jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def data_mesh():
-    return compat.make_mesh((8,), ("data",))
+    return jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
 
 # ---------------------------------------------------------------------------
@@ -57,9 +61,9 @@ def _():
 
     spec = P(("pod", "data"))
     f = shard_map(flat, mesh=mesh, in_specs=spec, out_specs=spec,
-                  check_rep=False)
+                  check_vma=False)
     h = shard_map(hier, mesh=mesh, in_specs=spec, out_specs=spec,
-                  check_rep=False)
+                  check_vma=False)
     # summation order differs (RS+AR+AG vs single ring): ~1e-6 rel noise
     np.testing.assert_allclose(np.asarray(f(x)), np.asarray(h(x)),
                                rtol=1e-5, atol=1e-7)
@@ -84,7 +88,7 @@ def _():
         return out["w"][None], new_r[None]
 
     f = shard_map(inner, mesh=mesh, in_specs=(P("data"), P("data")),
-                  out_specs=(P("data"), P("data")), check_rep=False)
+                  out_specs=(P("data"), P("data")), check_vma=False)
     synced, new_resid = f(g, resid)
     # every rank holds the same mean of dequantized peers
     from repro.kernels import ops
@@ -115,7 +119,7 @@ def _():
         return out["w"][None], new_r[None]
 
     f = shard_map(inner, mesh=mesh, in_specs=(P("data"), P("data")),
-                  out_specs=(P("data"), P("data")), check_rep=False)
+                  out_specs=(P("data"), P("data")), check_vma=False)
     synced, new_resid = f(g, resid)
     g_np = np.asarray(g)
     kept = np.zeros_like(g_np)
@@ -134,7 +138,7 @@ def _():
 @check("gpipe_matches_serial")
 def _():
     from repro.core import pipeline
-    mesh = compat.make_mesh((8,), ("stage",))
+    mesh = jax.make_mesh((8,), ("stage",), axis_types=(AxisType.Auto,))
     S, M, mb, d = 8, 16, 4, 32
     ks = jax.random.split(jax.random.PRNGKey(3), S)
     Ws = jnp.stack([jax.random.normal(k, (d, d)) * (d ** -0.5) for k in ks])
@@ -207,7 +211,7 @@ def _():
         pp["stage"], pp["last"], x_m.reshape((B, Sq, cfg.d_model)))
     g_x0 = g_x0.reshape(x_m.shape)
 
-    mesh = compat.make_mesh((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
     outs = {}
     # parity oracle #2: autodiff straight through the gpipe tick scan
     ad = jax.jit(pipeline.gpipe_value_and_grad(stage_fn, last_fn, mesh, 4,
@@ -468,12 +472,14 @@ def _():
     """launch/train.py drives the pipelined hybrid path end-to-end on the
     8-device mesh (the acceptance-criterion entrypoint)."""
     from repro.launch import train as launch_train
-    rc = launch_train.main([
+    # run() is main() minus the persistent compile cache, which tests
+    # leave off
+    res = launch_train.run(launch_train.build_parser().parse_args([
         "--arch", "olmo-1b", "--reduced", "--data", "2", "--model", "2",
         "--pp-stages", "2", "--pp-micro", "2", "--steps", "3",
         "--batch", "8", "--seq", "16",
-        "--ckpt-dir", "/tmp/repro_ppcheck_ckpt"])
-    assert rc == 0
+        "--ckpt-dir", "/tmp/repro_ppcheck_ckpt"]))
+    assert res.steps_run == 3 and all(np.isfinite(res.losses))
 
 
 # ---------------------------------------------------------------------------
@@ -581,7 +587,7 @@ def _():
     from repro.models import transformer as tf, model_zoo
     from repro.optimizer import adamw
     from repro.runtime import trainer
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     cfg = dataclasses.replace(reduced(get_arch("qwen3-moe-30b-a3b")),
                               dtype="float32", num_heads=2, num_kv_heads=2)
     plan = auto_plan(cfg, mesh, SHAPES["train_4k"], ParallelConfig())
@@ -624,7 +630,7 @@ def _():
     """Every sharding plan's lookup — and its gradient — matches the
     replicated-dense reference on the 8-device mesh."""
     from repro import embeddings
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(data=2, model=4)
     spec = embeddings.EmbedSpec("t", rows=96, dim=16)
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.normal(size=(96, 16)), jnp.float32)
@@ -663,7 +669,7 @@ def _():
         return sparse_row_sync(g_loc[0], ids_loc[0], ("data",))[None]
 
     f = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                  out_specs=P("data"), check_rep=False)
+                  out_specs=P("data"), check_vma=False)
     out = np.asarray(f(jnp.asarray(g), jnp.asarray(ids[:, None])))
     want = g.mean(0)
     for p in range(8):
@@ -738,7 +744,7 @@ def _():
     from repro.optimizer import adamw
     from repro.recsys import model as recsys_model
     from repro.runtime import trainer
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     cfg = dataclasses.replace(reduced(get_arch("recllm-base")),
                               dtype="float32")
     n_users = 64
@@ -789,7 +795,7 @@ def _():
     the rows-touched refresh restores exactness after a table update."""
     from repro import embeddings
     from repro.embeddings.serving import CacheConfig, CachedLookup
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(data=2, model=4)
     spec = embeddings.EmbedSpec("cf_item", rows=96, dim=16)
     rng = np.random.default_rng(7)
     table = rng.normal(size=(96, 16)).astype(np.float32)
@@ -823,7 +829,7 @@ def _():
     from repro.config import get_arch, reduced, SHAPES, ParallelConfig
     import repro.config as rc
     from repro.launch import dryrun_lib
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=2)
     cfg = reduced(get_arch("olmo-1b"))
     shape = dataclasses.replace(SHAPES["decode_32k"], seq_len=64,
                                 global_batch=8)

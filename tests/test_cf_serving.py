@@ -4,13 +4,13 @@ integration, and the cf_lookup_bytes comms model."""
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.embeddings import (CacheConfig, CachedLookup, EmbedSpec,
                               FreqTracker, HotRowCache, init_table,
                               make_plan)
 from repro.obs import MetricsRegistry, Tracer
 from repro.serving import (CFHead, Clock, EngineConfig, ServingEngine,
                            TrafficConfig, cf_lookup_bytes, generate)
+from repro.launch.mesh import make_host_mesh
 
 import jax
 
@@ -21,7 +21,7 @@ PLAN_KINDS = ["replicated", "row", "col", "row_col"]
 def mesh():
     # trivial 1x1 mesh: exercises every plan's shard_map code path
     # in-process without multi-device requirements.
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return make_host_mesh()
 
 
 @pytest.fixture(scope="module")

@@ -7,10 +7,10 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro import compat
 from repro.config import SHAPES, ParallelConfig, get_arch
 from repro.core import async_dp, hybrid, load_balance as lb
 from repro.runtime import straggler
+from repro.launch.mesh import make_host_mesh
 
 
 # -- expert rebalancing (LPT) -------------------------------------------------
@@ -145,7 +145,7 @@ def test_moe_flops_use_active_params():
 
 def test_auto_plan_remats_training():
     import jax
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     plan = hybrid.auto_plan(get_arch("internlm2-20b"), mesh,
                             SHAPES["train_4k"], ParallelConfig())
     assert plan.remat

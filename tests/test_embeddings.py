@@ -3,13 +3,15 @@ Pallas kernel vs ref parity, sparse gradients, and bit-for-bit parity of
 every sharding plan on a 1-device mesh (the multi-device parity lives in
 ``distributed_checks.py``)."""
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat, embeddings
+from repro import embeddings
 from repro.embeddings import update as embed_update
 from repro.kernels import ops
+from repro.launch.mesh import make_host_mesh
 
 
 def _table(rows=64, dim=16, seed=0):
@@ -137,8 +139,8 @@ def test_sparse_grad_from_lookup_equals_autodiff():
 def test_sparse_row_sync_single_device_bitwise():
     """On a 1-device mesh the rows-touched sync IS the dense gradient."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    mesh = compat.make_mesh((1,), ("data",))
+    from jax import shard_map
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     g = np.zeros((64, 16), np.float32)
     ids = np.asarray(_zipf_ids(20, 64))
     rng = np.random.default_rng(5)
@@ -147,7 +149,7 @@ def test_sparse_row_sync_single_device_bitwise():
 
     f = shard_map(
         lambda gs, i: embed_update.sparse_row_sync(gs, i, ("data",)),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
     out = f(jnp.asarray(g), jnp.asarray(ids))
     np.testing.assert_array_equal(np.asarray(out), g)
 
@@ -172,7 +174,7 @@ def test_row_compressor_keeps_topk_per_row():
 
 @pytest.mark.parametrize("kind", embeddings.PLANS)
 def test_sharded_lookup_single_device_bitwise(kind):
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     spec = embeddings.EmbedSpec("t", rows=64, dim=16)
     plan = embeddings.make_plan(kind)
     table = _table()
@@ -185,7 +187,7 @@ def test_sharded_lookup_single_device_bitwise(kind):
 
 
 def test_col_plan_requires_dp_axis():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     spec = embeddings.EmbedSpec("t", rows=64, dim=16)
     plan = embeddings.make_plan("col", col_axis="model")
     with pytest.raises(ValueError):

@@ -104,6 +104,22 @@ def test_paged_backend_dispatch_matrix():
         eng.make_backend(cfg_r, params_r, layout=PAGED.replace(kv_bits=8))
 
 
+def test_decode_step_donates_the_slot_state():
+    """The paged flash-decode step consumes its slot state (donation is on
+    for every backend, the CPU included): the step hands back a fresh
+    state, and a read of the donated one fails here as it would on the
+    chip."""
+    cfg, params, _ = _family_setup("uniform", n=1)
+    b = eng.make_backend(cfg, params, layout=PAGED.replace(impl="flash"))
+    state = b.init_slots(2, 64)
+    _, new_state = b.decode(state, np.ones((2, 1), np.int32))
+    donated = [x for x in jax.tree.leaves(state) if x.is_deleted()]
+    assert donated, "decode kept its input slot state alive"
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(donated[0])
+    assert not any(x.is_deleted() for x in jax.tree.leaves(new_state))
+
+
 def test_paged_slots_pages_only_linear_kv_leaves():
     """The generic composition pools exactly the append-at-len KV leaves:
     gemma's window-bounded rings and whisper's cross-KV stay slot-resident;

@@ -7,11 +7,11 @@ import numpy as np
 import pytest
 from numpy.testing import assert_allclose
 
-from repro import compat
 from repro.config import ParallelConfig, ShapeConfig, TrainConfig, \
     get_arch, reduced
 from repro.models import transformer as tf
 from repro.models.transformer import ModelCtx
+from repro.launch.mesh import make_host_mesh
 
 CTX = ModelCtx(attn_chunk=8)
 
@@ -66,7 +66,7 @@ def test_grad_accumulation_matches_monolithic():
     from repro.runtime import trainer
     cfg = dataclasses.replace(reduced(get_arch("olmo-1b")), num_layers=2,
                               dtype="float32")
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     shape = ShapeConfig("t", 16, 8, "train")
     tcfg = TrainConfig(steps=5, checkpoint_every=0, grad_clip=0.0)
     rng = np.random.default_rng(0)

@@ -4,15 +4,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.config import ParallelConfig, SHAPES, get_arch, reduced
 from repro.core.hybrid import auto_plan
 from repro.core.sharding import ShardingPlan, make_plan
 from repro.models import transformer as tf
+from repro.launch.mesh import make_host_mesh
 
 
 def mesh11():
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return make_host_mesh()
 
 
 @pytest.fixture(scope="module")
@@ -44,7 +44,7 @@ def test_embed_plan_routes_cf_tables():
     from the embeddings subsystem (row/col/2D) instead of the LM rules;
     non-dividing tables fall back to replication via the plan guard."""
     from repro.recsys import model as recsys_model
-    am = compat.abstract_mesh((4, 4), ("data", "model"))
+    am = jax.sharding.AbstractMesh((4, 4), ("data", "model"))
     shapes = {"cf_user": jax.ShapeDtypeStruct((64, 8), jnp.float32),
               "cf_item": jax.ShapeDtypeStruct((256, 8), jnp.float32),
               "odd": jax.ShapeDtypeStruct((63, 8), jnp.float32)}
@@ -70,7 +70,7 @@ def test_embed_plan_routes_cf_tables():
 def test_gqa_kv_replication_rule():
     """Production-mesh rules via AbstractMesh (no devices needed)."""
     import dataclasses
-    am = compat.abstract_mesh((16, 16), ("data", "model"))
+    am = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     sp = ShardingPlan(mesh=am, dp_axes=("data",), tp_axis="model")
     # guard: a dim of size 8 cannot shard over 16 — falls back to None
     assert sp.guard(("model",), (8,)) == P(None)
@@ -94,7 +94,7 @@ def test_moe_expert_rules(plan):
 
 
 def test_zero1_adds_dp_axis():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     sp = make_plan(mesh, ParallelConfig())
     z = sp.zero1_spec(P(None, "model"), (64, 32))
     assert z == P("data", "model")
@@ -110,7 +110,7 @@ def test_constrain_is_noop_without_real_sharding(plan):
 
 
 def test_auto_plan_dp_heavy_choice():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     # tp=1: dp_heavy not applicable
     plan = auto_plan(get_arch("internlm2-20b"), mesh, SHAPES["train_4k"])
     assert not plan.sharding.dp_heavy
