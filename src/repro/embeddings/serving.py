@@ -43,6 +43,7 @@ from repro.embeddings.lookup import make_sharded_lookup
 from repro.embeddings.table import (EmbedPlan, EmbedSpec, make_plan,
                                     named_sharding)
 from repro.embeddings.update import rows_touched
+from repro.obs.trace import NULL_TRACER
 
 
 class FreqTracker:
@@ -193,6 +194,8 @@ class CachedLookup:
                       if cache.rows > 0 else None)
         self.calls = 0
         self.exchanged_ids = 0          # ids that took the sharded path
+        self.host_syncs = 0             # device reads to the host
+        self.tracer, self.track = NULL_TRACER, "main"
 
     # -- cache bookkeeping ---------------------------------------------------
 
@@ -228,6 +231,7 @@ class CachedLookup:
     def _exchange(self, ids: np.ndarray) -> np.ndarray:
         """table[ids] through the sharded (or replicated) path."""
         n = len(ids)
+        self.host_syncs += 1
         if self._sharded is None:
             out = np.asarray(self._table_dev[jnp.asarray(ids, jnp.int32)])
             self.exchanged_ids += n
@@ -244,20 +248,26 @@ class CachedLookup:
         """(rows (n, D) float32 == table[ids] bit-for-bit, stats)."""
         flat = np.asarray(ids, np.int64).reshape(-1)
         self.calls += 1
+        tr, trk = self.tracer, self.track
         if self.cache is None:
-            rows = self._exchange(flat)
+            with tr.span("cf.gather", track=trk):
+                rows = self._exchange(flat)
             return rows, {"hits": 0, "misses": len(flat)}
-        self.cache.tracker.observe(flat)
-        hit, slots = self.cache.plan_lookup(flat)
-        rows = np.empty((len(flat), self.spec.dim), np.float32)
-        if hit.any():
-            rows[hit] = self.cache.rows[slots[hit]]
+        with tr.span("cf.cache.plan", track=trk):
+            self.cache.tracker.observe(flat)
+            hit, slots = self.cache.plan_lookup(flat)
+            rows = np.empty((len(flat), self.spec.dim), np.float32)
+            if hit.any():
+                rows[hit] = self.cache.rows[slots[hit]]
+            # the hits are copied out, so the head can be re-elected now:
+            # the misses come from the table, not the replica
+            if self.ccfg.elect_every and \
+                    self.calls % self.ccfg.elect_every == 0:
+                self.cache.refresh(self._host)
         n_miss = int((~hit).sum())
         if n_miss:
-            rows[~hit] = self._exchange(flat[~hit])
-        if self.ccfg.elect_every and \
-                self.calls % self.ccfg.elect_every == 0:
-            self.cache.refresh(self._host)
+            with tr.span("cf.gather", track=trk):
+                rows[~hit] = self._exchange(flat[~hit])
         return rows, {"hits": int(hit.sum()), "misses": n_miss}
 
     # -- table updates / staleness -------------------------------------------

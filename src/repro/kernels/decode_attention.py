@@ -220,11 +220,14 @@ def _pad_kv_len(x, block_k: int):
 
 
 def _run(kernel, prefetch, qg, kv_args, kv_specs, *, n_kv: int,
-         interpret: bool):
+         interpret: bool, name: str):
     """One ``pallas_call`` over grid ``(B, n_kv)``: every KV head of one
     slot's block ``ki`` per grid step.  KV tiles are ``(1, bk, Hk, D)`` —
     the head and feature dims whole, as the TPU's (8, 128) tiling
-    requires — and q / out / scratch carry all ``Hk`` heads of the slot."""
+    requires — and q / out / scratch carry all ``Hk`` heads of the slot.
+
+    ``name`` (``flash_decode...``) scopes the call, so the kernel's device
+    operation carries it in a profile, whatever jit wraps it."""
     B, Hk, rows, D = qg.shape
     n_pf = len(prefetch)
 
@@ -242,14 +245,15 @@ def _run(kernel, prefetch, qg, kv_args, kv_specs, *, n_kv: int,
             pltpu.VMEM((Hk, rows, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), qg.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*prefetch, qg, *kv_args)
+    with jax.named_scope(name):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, Hk, rows, D), qg.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(*prefetch, qg, *kv_args)
 
 
 def flash_decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
@@ -282,7 +286,7 @@ def flash_decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
     kv_spec = pl.BlockSpec((1, block_k, Hk, D), kv_map)
     out = _run(kernel, (lengths.astype(jnp.int32), _q_lens_or_full(
         q_lens, B, Sq)), qg, (k_cache, v_cache), [kv_spec, kv_spec],
-        n_kv=n_kv, interpret=interpret)
+        n_kv=n_kv, interpret=interpret, name="flash_decode")
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)
 
 
@@ -327,7 +331,8 @@ def flash_decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths, *,
     s_spec = pl.BlockSpec((1, Hk, block_k), scale_map)
     out = _run(kernel, (lengths.astype(jnp.int32), _q_lens_or_full(
         q_lens, B, Sq)), qg, (k_q, k_s, v_q, v_s),
-        [kv_spec, s_spec, kv_spec, s_spec], n_kv=n_kv, interpret=interpret)
+        [kv_spec, s_spec, kv_spec, s_spec], n_kv=n_kv, interpret=interpret,
+        name="flash_decode_int8")
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)
 
 
@@ -367,7 +372,7 @@ def flash_decode_attention_paged(q, k_pool, v_pool, block_tables, lengths, *,
                         _q_lens_or_full(q_lens, B, Sq),
                         block_tables.astype(jnp.int32)),
                qg, (k_pool, v_pool), [kv_spec, kv_spec], n_kv=nb,
-               interpret=interpret)
+               interpret=interpret, name="flash_decode_paged")
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)
 
 
@@ -411,5 +416,5 @@ def flash_decode_attention_paged_quant(q, k_q_pool, k_s_pool, v_q_pool,
                         block_tables.astype(jnp.int32)),
                qg, (k_q_pool, k_s_pool, v_q_pool, v_s_pool),
                [kv_spec, s_spec, kv_spec, s_spec], n_kv=nb,
-               interpret=interpret)
+               interpret=interpret, name="flash_decode_paged_int8")
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)

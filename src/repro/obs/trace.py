@@ -20,18 +20,31 @@ Time comes from an injectable ``clock`` callable (seconds).  Wall clock
 simulated :class:`~repro.serving.traffic.Clock`, and tests pin a
 :class:`ManualClock` for deterministic timelines.
 
+A live span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``: under a profiler session it lands on the device trace's
+host plane, on the same clock as the device operations, so an idle stretch
+of the chip can be put down to the span the host was in.  Outside a
+profiler session the annotation is a cheap no-op.  Retroactive spans and
+instants stay in the ring only.
+
 The disabled path is near-free: ``Tracer(enabled=False)`` (or the shared
 :data:`NULL_TRACER`) returns one module-level no-op context manager from
 every ``span()`` call and drops instants/completes before touching the
-clock — no event objects, no ring writes, no timestamps.  Hot call sites
-guard their *argument* computation (e.g. roofline models) behind
-``tracer.enabled`` so a disabled tracer costs one attribute check.
+clock — no event objects, no ring writes, no timestamps, no annotation.
+Hot call sites pass a span only arguments they already hold, and guard
+any argument that costs work to compute behind ``tracer.enabled``, so a
+disabled tracer costs one attribute check.
+
+``dropped`` counts the events the ring pushed out, so a reader can refuse
+a truncated timeline.
 """
 from __future__ import annotations
 
 import time
 from collections import deque
 from typing import Callable, Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class ManualClock:
@@ -60,21 +73,32 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        """Arguments known only at the span's exit: dropped."""
+
 
 _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """Live span handle: records (ts, dur, depth) on exit."""
+    """Live span handle: records (ts, dur, depth) on exit, and holds a
+    profiler annotation ``repro.<name>`` open while it runs."""
 
-    __slots__ = ("_tracer", "name", "track", "args", "t0", "depth")
+    __slots__ = ("_tracer", "name", "track", "args", "t0", "depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: str, args: Dict):
         self._tracer = tracer
         self.name, self.track, self.args = name, track, args
 
+    def set(self, **args) -> None:
+        """Add arguments known only at the span's exit (e.g. a lookup's
+        hit and miss counts)."""
+        self.args.update(args)
+
     def __enter__(self):
         tr = self._tracer
+        self._ann = TraceAnnotation("repro." + self.name)
+        self._ann.__enter__()
         self.depth = tr._depth.get(self.track, 0)
         tr._depth[self.track] = self.depth + 1
         self.t0 = tr.clock()
@@ -84,9 +108,10 @@ class _Span:
         tr = self._tracer
         t1 = tr.clock()
         tr._depth[self.track] = self.depth
-        tr._events.append({"ph": "X", "name": self.name, "track": self.track,
-                           "ts": self.t0, "dur": max(t1 - self.t0, 0.0),
-                           "depth": self.depth, "args": self.args})
+        tr._push({"ph": "X", "name": self.name, "track": self.track,
+                  "ts": self.t0, "dur": max(t1 - self.t0, 0.0),
+                  "depth": self.depth, "args": self.args})
+        self._ann.__exit__(None, None, None)
         return False
 
 
@@ -105,6 +130,12 @@ class Tracer:
         self.clock = clock if clock is not None else time.perf_counter
         self._events: deque = deque(maxlen=capacity)
         self._depth: Dict[str, int] = {}
+        self.dropped = 0            # events the full ring pushed out
+
+    def _push(self, ev: Dict) -> None:
+        if len(self._events) == self._events.maxlen:
+            self.dropped += 1
+        self._events.append(ev)
 
     @property
     def capacity(self) -> int:
@@ -128,25 +159,26 @@ class Tracer:
         this tracer's clock domain)."""
         if not self.enabled:
             return
-        self._events.append({"ph": "X", "name": name, "track": track,
-                             "ts": t0, "dur": max(t1 - t0, 0.0),
-                             "depth": self._depth.get(track, 0),
-                             "args": args})
+        self._push({"ph": "X", "name": name, "track": track,
+                    "ts": t0, "dur": max(t1 - t0, 0.0),
+                    "depth": self._depth.get(track, 0), "args": args})
 
     def instant(self, name: str, track: str = "main", **args) -> None:
         if not self.enabled:
             return
-        self._events.append({"ph": "i", "name": name, "track": track,
-                             "ts": self.clock(), "args": args})
+        self._push({"ph": "i", "name": name, "track": track,
+                    "ts": self.clock(), "args": args})
 
     def extend(self, events: Iterable[Dict]) -> None:
         """Merge finished events from another tracer (e.g. a probe-local
         tracer whose timeline should land in the session trace)."""
         if self.enabled:
-            self._events.extend(events)
+            for ev in events:
+                self._push(ev)
 
     def clear(self) -> None:
         self._events.clear()
+        self.dropped = 0
 
     def span_names(self) -> Dict[str, int]:
         """Event-count histogram by name — the cheap trace summary the

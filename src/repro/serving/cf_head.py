@@ -35,6 +35,7 @@ from jax.sharding import Mesh
 
 from repro.embeddings import EmbedSpec, init_table, make_plan
 from repro.embeddings.serving import CacheConfig, CachedLookup
+from repro.obs.trace import NULL_TRACER, Tracer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +84,21 @@ class CFHead:
                 plan, it, mesh=mesh, cache=cache),
         }
         self.requests_scored = 0
+        self._reads = 0         # its own device reads (the lookups count theirs)
+        self.set_tracer(NULL_TRACER)
+
+    def set_tracer(self, tracer: Tracer, track: str = "engine") -> None:
+        """Time each scoring phase on ``tracer`` (its serving engine's),
+        nested on ``track``; the lookups get it too."""
+        self.tracer, self.track = tracer, track
+        for lk in self.lookups.values():
+            lk.tracer, lk.track = tracer, track
+
+    @property
+    def host_syncs(self) -> int:
+        """Reads of device arrays to the host, each a blocking sync."""
+        return self._reads + sum(lk.host_syncs
+                                 for lk in self.lookups.values())
 
     @classmethod
     def build(cls, n_users: int, n_items: int, cf_dim: int = 16, *,
@@ -111,17 +127,25 @@ class CFHead:
         call.
         """
         from repro.recsys import model as rec_model
+        tr, trk = self.tracer, self.track
         cand = np.asarray(candidates, np.int64).reshape(-1)
-        u_rows, u_stats = self.lookups["cf_user"](np.asarray([user_id]))
-        i_rows, i_stats = self.lookups["cf_item"](cand)
-        cf = i_rows @ u_rows[0]                          # (C,) f32
-        if lm_logits_row is not None:
-            lm = np.asarray(lm_logits_row, np.float32)[cand]
-        else:
-            lm = np.zeros_like(cf)
-        fused = np.asarray(rec_model.fuse(jnp.asarray(lm), jnp.asarray(cf),
-                                          self.fusion_gate))
-        order = np.argsort(-fused, kind="stable")
+        with tr.span("cf.user", track=trk):
+            u_rows, u_stats = self.lookups["cf_user"](np.asarray([user_id]))
+        with tr.span("cf.items", track=trk):
+            i_rows, i_stats = self.lookups["cf_item"](cand)
+        with tr.span("cf.logits_row", track=trk):
+            if lm_logits_row is None:
+                lm = np.zeros(len(cand), np.float32)
+            else:
+                if isinstance(lm_logits_row, jax.Array):
+                    self._reads += 1
+                lm = np.asarray(lm_logits_row, np.float32)[cand]
+        with tr.span("cf.fuse_rank", track=trk):
+            cf = i_rows @ u_rows[0]                      # (C,) f32
+            fused = np.asarray(rec_model.fuse(
+                jnp.asarray(lm), jnp.asarray(cf), self.fusion_gate))
+            self._reads += 1
+            order = np.argsort(-fused, kind="stable")
         self.requests_scored += 1
         return {
             "cf": cf, "fused": fused,

@@ -43,14 +43,34 @@ deprecation window and now raise ``TypeError``.
 
 The engine is instrumented (see :mod:`repro.obs` and README
 "Observability"): hand :class:`ServingEngine` a ``tracer`` and/or
-``metrics`` registry and it pins both to its simulated clock, emits
-per-request phase spans (``req.queue_wait`` / ``req.prefill`` /
-``req.decode`` on one track per slot) built from the *same*
-:class:`~repro.serving.metrics.RequestRecord` timestamps the TTFT/TPOT
-report reads, per-step ``decode_step`` spans carrying modeled
-bytes/FLOPs/utilization from the roofline models, scheduler instants
-(``sched.admit`` / ``sched.reject`` / ``sched.shed`` /
-``sched.pushback``), and live block-pool gauges/counters.
+``metrics`` registry and it pins both to its clock.  Live spans on the
+``engine`` track time each phase of a tick where the work happens, and
+under a profiler session they are host annotations (``repro.<name>``) on
+the device trace's clock::
+
+    engine.tick
+      sched.refill
+        pool.admit         block-table admission + table upload
+        model.prefill      the prefill call, dispatch to wait
+        cf.lookup          the CF head (cf.user, cf.items, cf.logits_row,
+                           cf.fuse_rank; lookups: cf.cache.plan, cf.gather)
+        sample.first
+      engine.decode
+        pool.ensure_writable   copy-on-write
+        pool.sync_tables
+        decode_step        the decode call, dispatch to wait (``rows``)
+        sample.tokens      sampling and the tokens' read to the host
+        engine.retire      per-slot commit, finish, release
+
+Per-request phase spans (``req.queue_wait`` / ``req.prefill`` /
+``req.decode`` on one track per slot) are retroactive, built from the
+*same* :class:`~repro.serving.metrics.RequestRecord` timestamps the
+TTFT/TPOT report reads; scheduler instants (``sched.admit`` /
+``sched.reject`` / ``sched.shed`` / ``sched.pushback``) and live
+block-pool gauges/counters complete the picture.  Two plain counters need
+no tracer: ``ticks`` and ``host_syncs`` (each blocking wait of the engine
+or its CF head on the device, and each read of a device array to the
+host).
 
 Paged serving adds three scheduler-side pieces (see
 :mod:`repro.serving.block_pool`): admission maps a request's virtual
@@ -1093,7 +1113,8 @@ class ServingEngine:
         # engine's (simulated) clock so per-request span durations reconcile
         # with the TTFT/TPOT report by construction
         self.tracer = or_null(tracer)
-        self.tracer.clock = lambda: self.clock.now
+        if tracer is not None:
+            self.tracer.clock = lambda: self.clock.now
         self.metrics = metrics
         if metrics is not None:
             metrics.clock = lambda: self.clock.now
@@ -1198,6 +1219,12 @@ class ServingEngine:
         self.cf_head = cf_head
         self.cf_results: Dict[int, Dict] = {}
         self.cf_scored = 0
+        # the phase spans' track; the CF head's spans nest on it too
+        self._etrack = self._track("engine")
+        if cf_head is not None:
+            cf_head.set_tracer(self.tracer, self._etrack)
+        self.ticks = 0
+        self.host_syncs = 0     # blocking waits on, and reads from, the device
 
     # -- bookkeeping helpers -------------------------------------------------
 
@@ -1263,43 +1290,26 @@ class ServingEngine:
         tr.complete("req.decode", rec.first_token, rec.finished, track=track,
                     rid=rec.rid, tokens_out=rec.tokens_out)
 
-    def _decode_model_args(self) -> Dict:
-        """Modeled bytes/FLOPs/utilization for one decode step (roofline
-        models over the live per-slot lengths) — the args a traced
-        ``decode_step`` span carries so the timeline shows utilization,
-        not just wall time.  Empty for toy/test backends without a full
-        ArchConfig."""
-        cfg = getattr(self.backend, "cfg", None)
-        if cfg is None or not hasattr(cfg, "layer_kinds"):
-            return {}
-        from repro.core.hybrid import decode_model_flops
-        from repro.serving import roofline
-        lengths = [int(self._slot_len[s]) for s in range(self.ecfg.n_slots)
-                   if self.slot_req[s] is not None]
-        if not lengths:
-            return {}
-        rb = roofline.decode_attn_read_bytes(
-            cfg, lengths, self.ecfg.max_len,
-            impl=self.layout.impl or "dense", kv_bits=self.layout.kv_bits)
-        return {
-            "n_active": len(lengths),
-            "attn_read_bytes": rb["attn_read_bytes_per_step"],
-            "mean_utilization": rb["mean_utilization"],
-            "model_flops": decode_model_flops(
-                cfg, max(lengths), len(lengths)),
-        }
-
     @property
     def n_active(self) -> int:
         return sum(1 for r in self.slot_req if r is not None)
 
-    def _timed(self, fixed_s: Optional[float], fn):
+    def _timed(self, fixed_s: Optional[float], fn, wait: bool = True):
+        """Run ``fn`` and advance the clock by its time; ``wait``: its
+        output is on the device and the host blocks until it is ready."""
         t0 = time.perf_counter()
         out = fn()
-        jax.block_until_ready(out)
+        if wait:
+            jax.block_until_ready(out)
+            self.host_syncs += 1
         self.clock.advance(fixed_s if fixed_s is not None
                            else time.perf_counter() - t0)
         return out
+
+    def _read(self, x, dtype=None) -> np.ndarray:
+        """A device array read to the host: one blocking sync."""
+        self.host_syncs += 1
+        return np.asarray(x, dtype)
 
     # -- scheduler ops -------------------------------------------------------
 
@@ -1353,26 +1363,28 @@ class ServingEngine:
         out of the prefill logits.  Returns False — request untouched — when
         the block pool cannot map the request yet (paged admission): the
         caller requeues it behind the blocks that retiring slots free."""
+        tr, trk = self.tracer, self._etrack
         prompt = np.asarray(req.prompt, np.int32)
         if self.tables is not None:
-            bs = self.layout.block_size
-            if self.role == "prefill":
-                # tier advantage: a prefill engine maps only the prompt's
-                # blocks — the decode budget is reserved by the decode
-                # tier at import (pad-row writes past the prompt sink
-                # into the null block)
-                span = -(-len(prompt) // bs)
-            else:
-                span = -(-min(len(prompt) + req.max_new_tokens,
-                              self.ecfg.max_len) // bs)
-            if self.prefix_sharing:
-                keys, tail = prefix_keys(req.prompt, bs,
-                                         self._share_seed(req))
-            else:
-                keys, tail = [], None
-            if not self.tables.admit(slot, keys, tail, span):
-                return False
-            self._sync_tables()
+            with tr.span("pool.admit", track=trk):
+                bs = self.layout.block_size
+                if self.role == "prefill":
+                    # tier advantage: a prefill engine maps only the
+                    # prompt's blocks — the decode budget is reserved by
+                    # the decode tier at import (pad-row writes past the
+                    # prompt sink into the null block)
+                    span = -(-len(prompt) // bs)
+                else:
+                    span = -(-min(len(prompt) + req.max_new_tokens,
+                                  self.ecfg.max_len) // bs)
+                if self.prefix_sharing:
+                    keys, tail = prefix_keys(req.prompt, bs,
+                                             self._share_seed(req))
+                else:
+                    keys, tail = [], None
+                if not self.tables.admit(slot, keys, tail, span):
+                    return False
+                self._sync_tables()
         rec.admitted = self.clock.now
         self.tracer.instant("sched.admit", track=self._track("sched"),
                             rid=req.rid, slot=slot,
@@ -1386,10 +1398,11 @@ class ServingEngine:
             kwargs["frames"] = np.asarray(req.frames, np.float32)
         if getattr(self.backend, "needs_positions", False):
             kwargs["grid"] = req.grid    # text+patch mrope layout
-        logits_row, self.cache = self._timed(
-            self.clock.fixed_prefill_s,
-            lambda: self.backend.prefill(self.cache, padded,
-                                         len(prompt), slot, **kwargs))
+        with tr.span("model.prefill", track=trk, rid=req.rid, tokens=s_pad):
+            logits_row, self.cache = self._timed(
+                self.clock.fixed_prefill_s,
+                lambda: self.backend.prefill(self.cache, padded,
+                                             len(prompt), slot, **kwargs))
         self.prefills += 1
         self._slot_len[slot] = len(prompt)
         if self.tables is not None:
@@ -1401,18 +1414,19 @@ class ServingEngine:
             # Runs between prefill and the first-token stamp, so the CF
             # time lands inside the req.prefill span and the TTFT/span
             # reconciliation holds unchanged.
-            t_cf = self.clock.now
-            res = self._timed(
-                getattr(self.clock, "fixed_cf_s", None),
-                lambda: self.cf_head.score(req.user_id, req.candidates,
-                                           lm_logits_row=logits_row))
+            # the head returns host arrays: its own reads are its syncs
+            syncs = self.cf_head.host_syncs
+            with tr.span("cf.lookup", track=trk, rid=req.rid,
+                         candidates=len(req.candidates)) as sp:
+                res = self._timed(
+                    getattr(self.clock, "fixed_cf_s", None),
+                    lambda: self.cf_head.score(req.user_id, req.candidates,
+                                               lm_logits_row=logits_row),
+                    wait=False)
+                sp.set(hits=res["hits"], misses=res["misses"])
+            self.host_syncs += self.cf_head.host_syncs - syncs
             self.cf_results[req.rid] = res
             self.cf_scored += 1
-            self.tracer.complete("cf.lookup", t_cf, self.clock.now,
-                                 track=self._track(f"slot{slot}"),
-                                 rid=req.rid, hits=res["hits"],
-                                 misses=res["misses"],
-                                 candidates=len(req.candidates))
             if self.metrics is not None:
                 self.metrics.counter("cf_cache.hits").inc(res["hits"])
                 self.metrics.counter("cf_cache.misses").inc(res["misses"])
@@ -1420,9 +1434,11 @@ class ServingEngine:
                     self.cf_head.hit_rate)
                 self.metrics.gauge("cf_cache.rows").set(
                     self.cf_head.cache_rows_live)
-        key = self._request_key(req)
-        first = sample_token(logits_row, req.temperature, req.top_k,
-                             jax.random.fold_in(key, 0))
+        with tr.span("sample.first", track=trk):
+            key = self._request_key(req)
+            first = sample_token(logits_row, req.temperature, req.top_k,
+                                 jax.random.fold_in(key, 0))
+            self.host_syncs += 1            # the token read to the host
         rec.first_token = self.clock.now
         rec.tokens_out = 1
         if self.win is not None:
@@ -1440,7 +1456,7 @@ class ServingEngine:
             # hand the sealed prompt blocks + slot state to the decode
             # tier; this slot frees immediately, so the next queued
             # prompt prefills back-to-back (the tier's whole point)
-            self._export_request(slot, req, rec, first, np.asarray(key),
+            self._export_request(slot, req, rec, first, self._read(key),
                                  budget)
             return True
         self.slot_req[slot] = req
@@ -1448,7 +1464,7 @@ class ServingEngine:
         self.slot_remaining[slot] = budget - 1
         self.slot_tokens[slot, 0] = first
         self._tokens_dirty = True           # host wrote a slot: re-upload
-        self.slot_key[slot] = np.asarray(key)    # host copy: stacked later
+        self.slot_key[slot] = self._read(key)    # host copy: stacked later
         if getattr(self.backend, "needs_positions", False):
             # the first generated token's mrope position, one past the
             # prompt's layout (text continues all three components)
@@ -1570,14 +1586,19 @@ class ServingEngine:
         land ready handoffs, refill free slots from the queue, decode once
         if anything is active.  Returns False when nothing moved (the
         engine is blocked waiting on blocks or deliveries)."""
-        before = (self.prefills, self.decode_steps, self.handoffs_in,
-                  len(self.queue), len(self.handoff_inbox))
-        self._drain_inbox()
-        self._refill()
-        if self.n_active:
-            self._decode_once()
-        after = (self.prefills, self.decode_steps, self.handoffs_in,
-                 len(self.queue), len(self.handoff_inbox))
+        tr, trk = self.tracer, self._etrack
+        with tr.span("engine.tick", track=trk):
+            self.ticks += 1
+            before = (self.prefills, self.decode_steps, self.handoffs_in,
+                      len(self.queue), len(self.handoff_inbox))
+            self._drain_inbox()
+            with tr.span("sched.refill", track=trk):
+                self._refill()
+            active = self.n_active
+            if active:
+                self._decode_once(active)
+            after = (self.prefills, self.decode_steps, self.handoffs_in,
+                     len(self.queue), len(self.handoff_inbox))
         return after != before
 
     # -- refill -------------------------------------------------------------
@@ -1639,23 +1660,35 @@ class ServingEngine:
         if self.win is not None and rec.tpot is not None:
             self.win.observe_tpot(rec.tpot)
 
-    def _decode_once(self) -> None:
-        if self.spec_k > 1:
-            return self._spec_decode_once()
+    def _decode_once(self, rows: Optional[int] = None) -> None:
+        """One decode step; ``rows``: the active slots, which ``tick``
+        has counted already."""
+        if rows is None:
+            rows = self.n_active
+        with self.tracer.span("engine.decode", track=self._etrack):
+            if self.spec_k > 1:
+                self._spec_decode_once()
+            else:
+                self._single_decode_once(rows)
+
+    def _single_decode_once(self, rows: int) -> None:
+        tr, trk = self.tracer, self._etrack
         if self.tables is not None:
             # make every active slot's KV frontier exclusively owned before
             # the step writes there: COW off shared tails, claim sole-owner
             # sealed blocks, then upload the changed tables once
-            for s in range(self.ecfg.n_slots):
-                if self.slot_req[s] is None:
-                    continue
-                cow = self.tables.ensure_writable(s, int(self._slot_len[s]))
-                if cow is not None:
-                    self.cache = self.backend.copy_block(self.cache, *cow)
-                    self.tracer.instant("pool.cow",
-                                        track=self._track("pool"), slot=s,
-                                        src=cow[0], dst=cow[1])
-            self._sync_tables()
+            with tr.span("pool.ensure_writable", track=trk):
+                for s in range(self.ecfg.n_slots):
+                    if self.slot_req[s] is None:
+                        continue
+                    cow = self.tables.ensure_writable(s,
+                                                      int(self._slot_len[s]))
+                    if cow is not None:
+                        self.cache = self.backend.copy_block(self.cache, *cow)
+                        tr.instant("pool.cow", track=self._track("pool"),
+                                   slot=s, src=cow[0], dst=cow[1])
+            with tr.span("pool.sync_tables", track=trk):
+                self._sync_tables()
         positions = None
         if getattr(self.backend, "needs_positions", False):
             # (n, 1, 3): text decode advances t/h/w together per token
@@ -1672,67 +1705,62 @@ class ServingEngine:
         else:
             call = lambda: self.backend.decode(  # noqa: E731
                 self.cache, tokens, positions)
-        # span args (roofline-modeled bytes/FLOPs) are only computed when
-        # the tracer is live — the disabled path stays one attribute check
-        step_t0 = self.clock.now
-        step_args = self._decode_model_args() if self.tracer.enabled else None
-        logits, self.cache = self._timed(self.clock.fixed_decode_s, call)
-        if step_args is not None:
-            self.tracer.complete("decode_step", step_t0, self.clock.now,
-                                 track=self._track("engine"),
-                                 step=self.decode_steps, **step_args)
+        with tr.span("decode_step", track=trk, step=self.decode_steps,
+                     rows=rows):
+            logits, self.cache = self._timed(self.clock.fixed_decode_s, call)
         self.decode_steps += 1
-        self._kv_bytes_sum += self._resident_kv_bytes()
         self.slot_pos += 1
         n = self.ecfg.n_slots
-        any_sampled = any(r is not None and r.temperature > 0.0
-                          for r in self.slot_req)
-        if not any_sampled:
-            nxt_dev = _greedy_tokens(logits[:, 0, :])
-            nxt = np.asarray(nxt_dev, np.int32)
-        else:
-            # batched temperature/top-k/categorical over all slots: one
-            # device call, one host sync.  Per-slot keys fold with the
-            # token index inside the jit, so slot placement and batch
-            # composition never change a request's sampled stream (the
-            # semantics the scalar sample_token path established).
-            temps = np.zeros(n, np.float32)
-            topks = np.zeros(n, np.int32)
-            counts = np.zeros(n, np.int32)
-            keys = np.zeros((n, 2), np.uint32)
+        with tr.span("sample.tokens", track=trk):
+            any_sampled = any(r is not None and r.temperature > 0.0
+                              for r in self.slot_req)
+            if not any_sampled:
+                nxt_dev = _greedy_tokens(logits[:, 0, :])
+            else:
+                # batched temperature/top-k/categorical over all slots: one
+                # device call, one host sync.  Per-slot keys fold with the
+                # token index inside the jit, so slot placement and batch
+                # composition never change a request's sampled stream (the
+                # semantics the scalar sample_token path established).
+                temps = np.zeros(n, np.float32)
+                topks = np.zeros(n, np.int32)
+                counts = np.zeros(n, np.int32)
+                keys = np.zeros((n, 2), np.uint32)
+                for s in range(n):
+                    if self.slot_req[s] is None:
+                        continue
+                    temps[s] = self.slot_req[s].temperature
+                    topks[s] = self.slot_req[s].top_k
+                    counts[s] = self.slot_rec[s].tokens_out
+                    keys[s] = self.slot_key[s]
+                nxt_dev = _fold_and_sample(logits[:, 0, :], temps, topks,
+                                           keys, counts)
+            nxt = self._read(nxt_dev, np.int32)
+            # the sampled tokens are the next step's inputs and are already
+            # on device — keep them there instead of re-uploading from host
+            self._tokens_dev = nxt_dev[:, None].astype(jnp.int32)
+        with tr.span("engine.retire", track=trk):
+            self._kv_bytes_sum += self._resident_kv_bytes()
             for s in range(n):
-                if self.slot_req[s] is None:
+                req, rec = self.slot_req[s], self.slot_rec[s]
+                if req is None:
                     continue
-                temps[s] = self.slot_req[s].temperature
-                topks[s] = self.slot_req[s].top_k
-                counts[s] = self.slot_rec[s].tokens_out
-                keys[s] = self.slot_key[s]
-            nxt_dev = _fold_and_sample(logits[:, 0, :], temps, topks,
-                                       keys, counts)
-            nxt = np.asarray(nxt_dev, np.int32)
-        # the sampled tokens are the next step's inputs and are already on
-        # device — keep them there instead of re-uploading from host
-        self._tokens_dev = nxt_dev[:, None].astype(jnp.int32)
-        for s in range(n):
-            req, rec = self.slot_req[s], self.slot_rec[s]
-            if req is None:
-                continue
-            tok = int(nxt[s])
-            self.outputs[req.rid].append(tok)
-            rec.tokens_out += 1
-            self.slot_remaining[s] -= 1
-            self.slot_tokens[s, 0] = tok
-            self._slot_len[s] += 1          # this step's token landed
-            if tok == req.eos_id or self.slot_remaining[s] <= 0:
-                rec.finished = self.clock.now
-                self.slot_req[s] = None
-                self.slot_rec[s] = None
-                self.slot_key[s] = None
-                if self.tables is not None:
-                    self.tables.release(s)  # refcounts back to the pool
-                self._trace_request(rec, s)
-                self._note_finish(rec)
-        self._note_load()
+                tok = int(nxt[s])
+                self.outputs[req.rid].append(tok)
+                rec.tokens_out += 1
+                self.slot_remaining[s] -= 1
+                self.slot_tokens[s, 0] = tok
+                self._slot_len[s] += 1          # this step's token landed
+                if tok == req.eos_id or self.slot_remaining[s] <= 0:
+                    rec.finished = self.clock.now
+                    self.slot_req[s] = None
+                    self.slot_rec[s] = None
+                    self.slot_key[s] = None
+                    if self.tables is not None:
+                        self.tables.release(s)  # refcounts back to the pool
+                    self._trace_request(rec, s)
+                    self._note_finish(rec)
+            self._note_load()
 
     def _spec_decode_once(self) -> None:
         """One speculative scheduler step: self-draft up to ``spec_k - 1``
@@ -1770,20 +1798,22 @@ class ServingEngine:
             k_step *= 2
         k_step = min(k_step, k)
         rows = rows[:, :k_step]
+        tr, trk = self.tracer, self._etrack
         if self.tables is not None:
             # own the whole write span up front: one pass per touched
             # block regardless of k (batched COW)
-            for s in range(n):
-                if self.slot_req[s] is None:
-                    continue
-                for src, dst in self.tables.ensure_writable_span(
-                        s, int(self._slot_len[s]), int(q_lens[s])):
-                    self.cache = self.backend.copy_block(self.cache,
-                                                         src, dst)
-                    self.tracer.instant("pool.cow",
-                                        track=self._track("pool"), slot=s,
-                                        src=src, dst=dst)
-            self._sync_tables()
+            with tr.span("pool.ensure_writable", track=trk):
+                for s in range(n):
+                    if self.slot_req[s] is None:
+                        continue
+                    for src, dst in self.tables.ensure_writable_span(
+                            s, int(self._slot_len[s]), int(q_lens[s])):
+                        self.cache = self.backend.copy_block(self.cache,
+                                                             src, dst)
+                        tr.instant("pool.cow", track=self._track("pool"),
+                                   slot=s, src=src, dst=dst)
+            with tr.span("pool.sync_tables", track=trk):
+                self._sync_tables()
         positions = None
         if getattr(self.backend, "needs_positions", False):
             # (n, k_step, 3): text decode advances t/h/w together per row
@@ -1803,82 +1833,69 @@ class ServingEngine:
             q_dev = jnp.asarray(q_lens, jnp.int32)
             call = lambda: self.backend.decode_spec(  # noqa: E731
                 self.cache, tokens, q_dev, positions)
-        step_t0 = self.clock.now
-        step_args = self._decode_model_args() if self.tracer.enabled else None
-        live_rows = int(q_lens[[s for s in range(n)
-                                if self.slot_req[s] is not None]].sum())
-        if step_args:
-            # the verify pass runs q_len rows per slot through the model:
-            # FLOPs scale with live rows, while attn_read_bytes stays the
-            # single-step figure (the cache streams once per STEP — the
-            # perf win speculative decode is buying)
-            step_args["model_flops"] *= live_rows / step_args["n_active"]
-            step_args["spec_q_rows"] = live_rows
-        logits, accepts_dev, self.cache = self._timed(
-            self.clock.fixed_decode_s, call)
+        live = [s for s in range(n) if self.slot_req[s] is not None]
+        live_rows = int(q_lens[live].sum())
+        with tr.span("decode_step", track=trk, step=self.decode_steps,
+                     rows=len(live), q_rows=live_rows):
+            logits, accepts_dev, self.cache = self._timed(
+                self.clock.fixed_decode_s, call)
         self.decode_steps += 1
-        self._kv_bytes_sum += self._resident_kv_bytes()
-        emitted_np = np.asarray(_greedy_tokens(logits), np.int64)  # (n, k)
-        accepts = np.asarray(accepts_dev, np.int64)
-        sampled = None
-        if any(r is not None and r.temperature > 0.0
-               for r in self.slot_req):
-            temps = np.zeros(n, np.float32)
-            topks = np.zeros(n, np.int32)
-            counts = np.zeros(n, np.int32)
-            keys = np.zeros((n, 2), np.uint32)
-            for s in range(n):
-                if self.slot_req[s] is None:
-                    continue
-                temps[s] = self.slot_req[s].temperature
-                topks[s] = self.slot_req[s].top_k
-                counts[s] = self.slot_rec[s].tokens_out
-                keys[s] = self.slot_key[s]
-            sampled = np.asarray(_fold_and_sample(logits[:, 0, :], temps,
-                                                  topks, keys, counts),
-                                 np.int32)
+        with tr.span("sample.tokens", track=trk):
+            emitted_np = self._read(_greedy_tokens(logits), np.int64)
+            accepts = self._read(accepts_dev, np.int64)
+            sampled = None
+            if any(r is not None and r.temperature > 0.0
+                   for r in self.slot_req):
+                temps = np.zeros(n, np.float32)
+                topks = np.zeros(n, np.int32)
+                counts = np.zeros(n, np.int32)
+                keys = np.zeros((n, 2), np.uint32)
+                for s in range(n):
+                    if self.slot_req[s] is None:
+                        continue
+                    temps[s] = self.slot_req[s].temperature
+                    topks[s] = self.slot_req[s].top_k
+                    counts[s] = self.slot_rec[s].tokens_out
+                    keys[s] = self.slot_key[s]
+                sampled = self._read(_fold_and_sample(
+                    logits[:, 0, :], temps, topks, keys, counts), np.int32)
         self._tokens_dirty = True       # host builds next step's draft rows
         step_emitted = 0
-        self.spec_slot_steps += sum(r is not None for r in self.slot_req)
+        self.spec_slot_steps += len(live)
         self.spec_rows += live_rows
-        for s in range(n):
-            req, rec = self.slot_req[s], self.slot_rec[s]
-            if req is None:
-                continue
-            a = int(accepts[s])
-            if req.temperature > 0.0:
-                toks = [int(sampled[s])]       # a == 1 (q_len was 1)
-            else:
-                toks = [int(t) for t in emitted_np[s, :a]]
-            # stop at the first EOS (the device cache over-commits the
-            # rows behind it, but a finishing slot's state is discarded)
-            eos_at = next((j for j, t in enumerate(toks)
-                           if t == req.eos_id), None)
-            if eos_at is not None:
-                toks = toks[:eos_at + 1]
-            self.outputs[req.rid].extend(toks)
-            rec.tokens_out += len(toks)
-            step_emitted += len(toks)
-            self.slot_remaining[s] -= len(toks)
-            self._slot_len[s] += a          # device KV frontier: accepts
-            self.slot_pos[s] += a
-            self.slot_tokens[s, 0] = toks[-1]
-            if eos_at is not None or self.slot_remaining[s] <= 0:
-                rec.finished = self.clock.now
-                self.slot_req[s] = None
-                self.slot_rec[s] = None
-                self.slot_key[s] = None
-                if self.tables is not None:
-                    self.tables.release(s)
-                self._trace_request(rec, s)
-                self._note_finish(rec)
-        self._note_load()
+        with tr.span("engine.retire", track=trk):
+            self._kv_bytes_sum += self._resident_kv_bytes()
+            for s in live:
+                req, rec = self.slot_req[s], self.slot_rec[s]
+                a = int(accepts[s])
+                if req.temperature > 0.0:
+                    toks = [int(sampled[s])]       # a == 1 (q_len was 1)
+                else:
+                    toks = [int(t) for t in emitted_np[s, :a]]
+                # stop at the first EOS (the device cache over-commits the
+                # rows behind it, but a finishing slot's state is discarded)
+                eos_at = next((j for j, t in enumerate(toks)
+                               if t == req.eos_id), None)
+                if eos_at is not None:
+                    toks = toks[:eos_at + 1]
+                self.outputs[req.rid].extend(toks)
+                rec.tokens_out += len(toks)
+                step_emitted += len(toks)
+                self.slot_remaining[s] -= len(toks)
+                self._slot_len[s] += a          # device KV frontier: accepts
+                self.slot_pos[s] += a
+                self.slot_tokens[s, 0] = toks[-1]
+                if eos_at is not None or self.slot_remaining[s] <= 0:
+                    rec.finished = self.clock.now
+                    self.slot_req[s] = None
+                    self.slot_rec[s] = None
+                    self.slot_key[s] = None
+                    if self.tables is not None:
+                        self.tables.release(s)
+                    self._trace_request(rec, s)
+                    self._note_finish(rec)
+            self._note_load()
         self.spec_tokens += step_emitted
-        if step_args is not None:
-            self.tracer.complete("decode_step", step_t0, self.clock.now,
-                                 track=self._track("engine"),
-                                 step=self.decode_steps - 1,
-                                 tokens_emitted=step_emitted, **step_args)
         if self.metrics is not None:
             self.metrics.counter("engine.spec_tokens").inc(step_emitted)
 
@@ -1894,13 +1911,14 @@ class ServingEngine:
             while i < len(reqs) and reqs[i].arrival <= self.clock.now:
                 self.submit(reqs[i])
                 i += 1
-            self._refill()
-            if self.n_active:
-                self._decode_once()
-                continue
-            if self.queue:
-                # every slot free + non-empty queue should have refilled
-                raise RuntimeError("scheduler stalled with queued work")
+            if self.has_work:
+                steps = self.decode_steps
+                self.tick()
+                if self.decode_steps != steps:
+                    continue
+                if self.queue:
+                    # every slot free + non-empty queue should have refilled
+                    raise RuntimeError("scheduler stalled with queued work")
             if i < len(reqs):
                 self.clock.advance(reqs[i].arrival - self.clock.now)
                 continue

@@ -86,6 +86,97 @@ def test_extend_merges_probe_tracer():
     assert main.span_names() == {"stage_tick": 1}
 
 
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+        self.log.append(("new", name))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_live_span_is_a_profiler_annotation(monkeypatch):
+    from repro.obs import trace
+    monkeypatch.setattr(trace, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.log = []
+    tr = Tracer(clock=ManualClock())
+    with tr.span("engine.tick", track="engine"):
+        with tr.span("decode_step", track="engine"):
+            pass
+    assert _FakeAnnotation.log == [
+        ("new", "repro.engine.tick"), ("enter", "repro.engine.tick"),
+        ("new", "repro.decode_step"), ("enter", "repro.decode_step"),
+        ("exit", "repro.decode_step"), ("exit", "repro.engine.tick")]
+    # retroactive spans and instants are not annotated; a disabled tracer
+    # opens no annotation at all
+    tr.complete("req.prefill", 0.0, 1.0)
+    tr.instant("sched.admit")
+    off = Tracer(enabled=False)
+    with off.span("engine.tick"):
+        pass
+    assert len(_FakeAnnotation.log) == 6
+
+
+def test_live_spans_reach_the_profiler(tmp_path):
+    """Under a real profiler session the spans land on a host plane of the
+    trace, nested as they ran."""
+    import glob
+
+    import jax
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("engine.tick", track="engine"):
+            with tr.span("decode_step", track="engine"):
+                jax.block_until_ready(jax.numpy.ones(8) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    evs = {e.name: (e.start_ns, e.end_ns)
+           for pl in pd.planes if pl.name.startswith("/host:")
+           for ln in pl.lines for e in ln.events
+           if e.name.startswith("repro.")}
+    assert set(evs) == {"repro.engine.tick", "repro.decode_step"}
+    (t0, t1), (s0, s1) = evs["repro.engine.tick"], evs["repro.decode_step"]
+    assert t0 <= s0 <= s1 <= t1
+
+
+def test_span_args_known_at_exit():
+    tr = Tracer(clock=ManualClock())
+    with tr.span("cf.lookup", track="engine", rid=4) as sp:
+        sp.set(hits=3, misses=1)
+    assert tr.events[-1]["args"] == {"rid": 4, "hits": 3, "misses": 1}
+    off = Tracer(enabled=False)
+    with off.span("cf.lookup", rid=4) as sp:
+        assert sp is _NOOP_SPAN
+        sp.set(hits=3, misses=1)
+    assert off.events == []
+
+
+def test_ring_overflow_counts_dropped():
+    tr = Tracer(capacity=4, clock=ManualClock())
+    for i in range(3):
+        tr.instant("e", i=i)
+    assert tr.dropped == 0
+    with tr.span("s"):
+        pass
+    tr.complete("c", 0.0, 1.0)
+    tr.extend([{"ph": "i", "name": "x", "track": "m", "ts": 0, "args": {}}
+               for _ in range(3)])
+    assert tr.dropped == 4 and len(tr.events) == 4
+    tr.clear()
+    assert tr.dropped == 0 and tr.events == []
+
+
 # ---------------------------------------------------------------------------
 # exporters
 # ---------------------------------------------------------------------------
@@ -259,12 +350,14 @@ def test_engine_spans_reconcile_with_ttft_tpot():
     admits = [e for e in tracer.events
               if e["ph"] == "i" and e["name"] == "sched.admit"]
     assert len(admits) >= len(finished)
-    # decode_step spans ride the engine track with modeled roofline args
+    # decode_step spans ride the engine track; each carries the rows it
+    # decoded, one token per active slot past each request's first
     steps = [e for e in tracer.events if e["name"] == "decode_step"]
     assert len(steps) == summary["decode_steps"]
     assert steps[0]["track"] == "engine"
-    assert steps[0]["args"]["attn_read_bytes"] > 0
-    assert steps[0]["args"]["model_flops"] > 0
+    assert all(1 <= e["args"]["rows"] <= 2 for e in steps)
+    assert sum(e["args"]["rows"] for e in steps) == \
+        sum(r.tokens_out - 1 for r in finished)
     # summary carries the obs section; pool metrics landed in the registry
     assert summary["obs"]["span_counts"]["decode_step"] == len(steps)
     snap = registry.snapshot()
@@ -298,6 +391,189 @@ def test_untraced_engine_summary_has_no_obs():
     _, _, summary = engine.run(reqs)
     assert "obs" not in summary
     assert not engine.tracer.enabled
+
+
+# ---------------------------------------------------------------------------
+# serving engine: live phase spans and counters, on a tiny paged engine
+# with a CF head
+# ---------------------------------------------------------------------------
+
+class _WallClock:
+    """The engine's clock read from the host, as a deployment runs it."""
+
+    fixed_decode_s = fixed_prefill_s = fixed_handoff_s = fixed_cf_s = None
+
+    def __init__(self):
+        import time
+        self._t0 = time.perf_counter()
+
+    @property
+    def now(self) -> float:
+        import time
+        return time.perf_counter() - self._t0
+
+    def advance(self, dt: float) -> None:
+        pass
+
+
+def _tiny_cf_engine(tracer=None, n_requests: int = 6):
+    import jax
+
+    from repro.cache_layout import CacheLayout
+    from repro.config import get_arch, reduced
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import transformer as tf
+    from repro.serving import CFHead
+    from repro.serving import engine as eng
+    from repro.serving import traffic
+
+    cfg = dataclasses.replace(reduced(get_arch("olmo-1b")), dtype="float32")
+    params = tf.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(1)
+    reqs = [traffic.Request(
+        rid=i, user_id=i % 3,
+        prompt=tuple(int(t) for t in rng.integers(3, cfg.vocab_size,
+                                                  int(rng.integers(4, 20)))),
+        max_new_tokens=int(rng.integers(2, 6)), arrival=0.0,
+        candidates=tuple(int(c) for c in rng.choice(cfg.vocab_size, 6,
+                                                    replace=False)))
+        for i in range(n_requests)]
+    layout = CacheLayout(kind="paged", block_size=8)
+    head = CFHead.build(n_users=3, n_items=cfg.vocab_size, cf_dim=8,
+                        plan="row", cache_rows=4, mesh=make_host_mesh())
+    engine = eng.ServingEngine(
+        eng.make_backend(cfg, params, layout=layout),
+        eng.EngineConfig(n_slots=2, max_len=64, layout=layout),
+        clock=_WallClock(), tracer=tracer, cf_head=head)
+    return engine, reqs
+
+
+# the phase tree of one tick: each live span's parent
+_PARENT = {
+    "sched.refill": {"engine.tick"}, "engine.decode": {"engine.tick"},
+    "pool.admit": {"sched.refill"}, "model.prefill": {"sched.refill"},
+    "cf.lookup": {"sched.refill"}, "sample.first": {"sched.refill"},
+    "cf.user": {"cf.lookup"}, "cf.items": {"cf.lookup"},
+    "cf.logits_row": {"cf.lookup"}, "cf.fuse_rank": {"cf.lookup"},
+    "cf.cache.plan": {"cf.user", "cf.items"},
+    "cf.gather": {"cf.user", "cf.items"},
+    "pool.ensure_writable": {"engine.decode"},
+    "pool.sync_tables": {"engine.decode"},
+    "decode_step": {"engine.decode"}, "sample.tokens": {"engine.decode"},
+    "engine.retire": {"engine.decode"},
+}
+
+
+def _inside(child, parent) -> bool:
+    return (parent["ts"] <= child["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 1e-12)
+
+
+def _traced_ticks():
+    tracer = Tracer()
+    engine, reqs = _tiny_cf_engine(tracer)
+    for r in reqs:
+        engine.submit(r)
+    decoded = []
+    while engine.has_work:
+        steps = engine.decode_steps
+        engine.tick()
+        decoded.append(engine.decode_steps > steps)
+    live = [e for e in tracer.events
+            if e["ph"] == "X" and e["track"] == "engine"]
+    return engine, tracer, decoded, live
+
+
+def test_each_decoding_tick_has_one_tick_span_around_its_decode():
+    engine, tracer, decoded, live = _traced_ticks()
+    assert engine.ticks == len(decoded) and any(decoded)
+    # children exit first: a tick's events are those up to its own
+    groups, cur = [], []
+    for e in live:
+        cur.append(e)
+        if e["name"] == "engine.tick":
+            groups.append(cur)
+            cur = []
+    assert cur == [] and len(groups) == len(decoded)
+    for group, did in zip(groups, decoded):
+        tick = group[-1]
+        names = [e["name"] for e in group]
+        assert tick["depth"] == 0 and names.count("engine.tick") == 1
+        assert names.count("engine.decode") == names.count("decode_step") \
+            == int(did)
+        if did:
+            dec = group[names.index("engine.decode")]
+            step = group[names.index("decode_step")]
+            assert (dec["depth"], step["depth"]) == (1, 2)
+            assert _inside(step, dec) and _inside(dec, tick)
+    assert tracer.dropped == 0
+
+
+def test_admission_span_does_not_count_as_the_admission_instant():
+    """The block-table admission span has a name of its own: the
+    ``sched.admit`` count stays one scheduler instant per admission."""
+    engine, tracer, decoded, live = _traced_ticks()
+    counts = tracer.span_names()
+    assert counts["sched.admit"] == engine.prefills > 0
+    assert counts["pool.admit"] == engine.prefills
+    assert all(e["ph"] == "i" for e in tracer.events
+               if e["name"] == "sched.admit")
+
+
+def test_children_never_outlast_their_parent():
+    engine, tracer, decoded, live = _traced_ticks()
+    assert {e["name"] for e in live} == set(_PARENT) | {"engine.tick"}
+    for i, e in enumerate(live):
+        if e["depth"] == 0:
+            assert e["name"] == "engine.tick"
+            continue
+        # the parent is the next span one level up to close
+        parent = next(p for p in live[i + 1:] if p["depth"] == e["depth"] - 1)
+        assert parent["name"] in _PARENT[e["name"]], (e, parent)
+        assert _inside(e, parent), (e, parent)
+
+
+def test_host_syncs_count_every_wait_on_the_device(monkeypatch):
+    """Every wait on the device that a counting probe sees inside the
+    engine's ticks (``block_until_ready`` on device arrays, and each read
+    of one to the host) is one ``host_syncs``; untraced, as deployed."""
+    import jax
+    from jax._src import array
+
+    engine, reqs = _tiny_cf_engine(None)
+    for r in reqs:
+        engine.submit(r)
+    seen = [0]
+    real_wait, real_asarray = jax.block_until_ready, np.asarray
+
+    def wait(x):
+        if any(isinstance(v, jax.Array) for v in jax.tree.leaves(x)):
+            seen[0] += 1
+        return real_wait(x)
+
+    def asarray(x, *a, **k):
+        if isinstance(x, jax.Array):
+            seen[0] += 1
+        return real_asarray(x, *a, **k)
+
+    def counted(real):
+        def read(self, *a, **k):
+            seen[0] += 1
+            return real(self, *a, **k)
+        return read
+
+    with monkeypatch.context() as m:
+        m.setattr(jax, "block_until_ready", wait)
+        m.setattr(np, "asarray", asarray)
+        for name in ("__int__", "__float__", "__bool__", "__index__",
+                     "item", "tolist"):
+            m.setattr(array.ArrayImpl, name,
+                      counted(getattr(array.ArrayImpl, name)))
+        while engine.has_work:
+            engine.tick()
+    assert engine.prefills == len(reqs) and engine.cf_scored == len(reqs)
+    assert engine.host_syncs == seen[0] > 0
+    assert engine.ticks > 0 and engine.tracer is NULL_TRACER
 
 
 # ---------------------------------------------------------------------------

@@ -79,6 +79,28 @@ def test_flash_decode_compiles(chip, variant, paged):
              *_decode_args(chip, variant == "int8", paged))
 
 
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_flash_decode_op_carries_the_kernel_name(chip, paged, quant):
+    """The kernel's device operation is named for the kernel, not for the
+    jit that wraps it, so a profile finds it as ``flash_decode*``."""
+    import re
+    name = "flash_decode" + ("_paged" if paged else "") + \
+        ("_int8" if quant else "")
+    fn = {(False, False): dk.flash_decode_attention,
+          (False, True): dk.flash_decode_attention_quant,
+          (True, False): dk.flash_decode_attention_paged,
+          (True, True): dk.flash_decode_attention_paged_quant}[paged, quant]
+
+    def wrapper(*a):
+        return fn(*a)
+
+    text = _compile(wrapper, *_decode_args(chip, quant, paged)).as_text()
+    ops_ = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                      text)
+    assert [re.sub(r"(\.\d+)+$", "", o) for o in ops_] == [name], ops_
+
+
 def test_flash_decode_spec_rows_compile(chip):
     """k-row speculative verification: q_lens prefetched per slot."""
     args = _decode_args(chip, False, True)
