@@ -44,16 +44,18 @@ Three fused variants share the one kernel body:
 
 **Paged** variants (:func:`flash_decode_attention_paged`,
 :func:`flash_decode_attention_paged_quant`) read the same kernel body
-against a *shared block pool* ``(num_blocks, block_size, Hk, D)`` plus a
-per-slot block table ``(B, blocks_per_slot)``: the block table is scalar-
-prefetched alongside ``lengths`` and the KV BlockSpec index map becomes a
-table lookup — grid step ``ki`` of slot ``b`` fetches physical block
-``tables[b, ki]`` instead of contiguous row-block ``ki``.  Virtual
-positions are still ``ki * block_size + iota``, so the full / window / ring
-masks and the length-skipping clamp are identical to the dense-layout
-kernel; only *where a block's rows live* changes.  Dead table entries point
-at the reserved null block 0 and are never touched (the clamp keeps ``ki``
-inside the live range).
+against a *shared block pool* ``(num_blocks, block_size, Hk, D)`` (the
+bf16 one: a layer-stacked ``(L, num_blocks, ...)`` pool, the layer's
+offset scalar-prefetched) plus a per-slot block table
+``(B, blocks_per_slot)``: the block table is scalar-prefetched alongside
+``lengths`` and the KV
+BlockSpec index map becomes a table lookup — grid step ``ki`` of slot
+``b`` fetches physical block ``tables[b, ki]`` instead of contiguous
+row-block ``ki``.  Virtual positions are still ``ki * block_size + iota``,
+so the full / window / ring masks and the length-skipping clamp are
+identical to the dense-layout kernel; only *where a block's rows live*
+changes.  Dead table entries point at the reserved null block 0 and are
+never touched (the clamp keeps ``ki`` inside the live range).
 
 **Speculative multi-token verification** generalizes every variant from one
 query row to ``Sq = k`` draft rows per slot, folded into the kernel's row
@@ -337,42 +339,56 @@ def flash_decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths, *,
 
 
 def flash_decode_attention_paged(q, k_pool, v_pool, block_tables, lengths, *,
-                                 window: int = 0, ring: bool = False,
+                                 layer=0, window: int = 0, ring: bool = False,
                                  softmax_scale=None,
                                  interpret: bool = False, q_lens=None):
-    """Paged flash decode: q (B, Sq, H, D); k/v pools (N, bs, Hk, D) shared
-    across slots; block_tables (B, nb) int32 physical block ids; lengths
-    (B,) live virtual prefix.  The KV tile is one pool block (``block_k ==
-    block_size``) and the index map dereferences the prefetched table.
-    ``q_lens`` enables k-row speculative verification — the live-block
-    clamp covers the draft span, so a draft crossing a block boundary
-    fetches both touched blocks."""
+    """Paged flash decode: q (B, Sq, H, D); k/v pools (L, N, bs, Hk, D)
+    stacked over layers and shared across slots, attended at layer
+    ``layer`` (a scalar, traced or not); block_tables (B, nb) int32
+    physical block ids; lengths (B,) live virtual prefix.  A pool without
+    the layer axis, (N, bs, Hk, D), is the ``L = 1`` case.
+
+    The kernel reads its blocks straight out of the stacked pool, so a
+    caller that keeps every layer's pool in one buffer (the decode step's
+    layer scan) never slices a layer out of it: the pool is viewed as
+    ``(L*N, bs, Hk, D)`` (merging the two leading dims is a bitcast), the
+    layer's first row ``layer * N`` is scalar-prefetched with the tables,
+    and the KV index map returns ``layer * N + tables[b, ki]``.  The KV
+    tile is one pool block (``block_k == block_size``).  ``q_lens``
+    enables k-row speculative verification — the live-block clamp covers
+    the draft span, so a draft crossing a block boundary fetches both
+    touched blocks."""
+    if k_pool.ndim == 4:                     # one layer: L = 1
+        k_pool, v_pool = k_pool[None], v_pool[None]
     B, Sq, H, D = q.shape
-    N, bs, Hk, _ = k_pool.shape
+    L, N, bs, Hk, _ = k_pool.shape
     nb = block_tables.shape[1]
     S = nb * bs                              # virtual position space
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     qg, Sq, G, G_pad = _prep_q(q, Hk)
 
-    def kv_map(b, ki, lens, qlens, tables):
+    def kv_map(b, ki, lens, qlens, tables, base):
         lo, hi = _live_block_bounds(lens[b], bs, S, window, ring, qlens[b])
-        return (tables[b, jnp.clip(ki, lo, hi)], 0, 0, 0)
+        return (base[0] + tables[b, jnp.clip(ki, lo, hi)], 0, 0, 0)
 
     kernel_body = functools.partial(
         _decode_kernel, scale=scale, window=window, ring=ring,
         block_k=bs, n_kv=nb, S=S, g_pad=G_pad)
 
-    def kernel(lens_ref, qlens_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
-               m_scr, l_scr, acc_scr):
+    def kernel(lens_ref, qlens_ref, tables_ref, base_ref, q_ref, k_ref,
+               v_ref, o_ref, m_scr, l_scr, acc_scr):
         kernel_body(lens_ref, qlens_ref, q_ref, k_ref, v_ref, o_ref,
                     m_scr, l_scr, acc_scr)
 
     kv_spec = pl.BlockSpec((1, bs, Hk, D), kv_map)
+    base = (jnp.asarray(layer, jnp.int32) * N).reshape(1)
     out = _run(kernel, (lengths.astype(jnp.int32),
                         _q_lens_or_full(q_lens, B, Sq),
-                        block_tables.astype(jnp.int32)),
-               qg, (k_pool, v_pool), [kv_spec, kv_spec], n_kv=nb,
-               interpret=interpret, name="flash_decode_paged")
+                        block_tables.astype(jnp.int32), base),
+               qg, (k_pool.reshape(L * N, bs, Hk, D),
+                    v_pool.reshape(L * N, bs, Hk, D)),
+               [kv_spec, kv_spec], n_kv=nb, interpret=interpret,
+               name="flash_decode_paged")
     return _unprep_out(out, B, Sq, H, D, G, G_pad, Hk)
 
 

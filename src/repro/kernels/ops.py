@@ -93,7 +93,8 @@ def _record_decode_dispatch(q, cache, layout) -> None:
     # cache positions per slot: pool blocks * block_size when paged, else
     # the padded row length
     if layout.paged:
-        pool = cache["k" if "k" in cache else "k_q"]
+        if "k" in cache and cache["k"].ndim == 5:
+            kv_bytes //= cache["k"].shape[0]     # one layer of the stack
         S = int(cache["block_table"].shape[1]) * layout.block_size
     else:
         S = int(cache["k" if "k" in cache else "k_q"].shape[-3])
@@ -110,17 +111,18 @@ def _record_decode_dispatch(q, cache, layout) -> None:
 
 
 def decode_attention(q, cache, lengths, *, layout, softmax_scale=None,
-                     q_lens=None):
+                     q_lens=None, layer=0):
     """Dispatch-recording wrapper over :func:`_decode_attention_jit` —
     the public entry point every model/backend calls."""
     _record_decode_dispatch(q, cache, layout)
     return _decode_attention_jit(q, cache, lengths, layout=layout,
-                                 softmax_scale=softmax_scale, q_lens=q_lens)
+                                 softmax_scale=softmax_scale, q_lens=q_lens,
+                                 layer=layer)
 
 
 @partial(jax.jit, static_argnames=("layout", "softmax_scale"))
 def _decode_attention_jit(q, cache, lengths, *, layout, softmax_scale=None,
-                          q_lens=None):
+                          q_lens=None, layer=0):
     """THE decode-attention entry point, keyed off one
     :class:`repro.cache_layout.CacheLayout` instead of four separate
     wrappers.  ``cache`` is a dict whose keys the layout determines:
@@ -128,7 +130,10 @@ def _decode_attention_jit(q, cache, lengths, *, layout, softmax_scale=None,
     * dense bf16 — ``{"k", "v"}`` with (B, S, Hk, D) per-slot rows;
     * dense int8 — ``{"k_q", "k_s", "v_q", "v_s"}`` (scales (B, S, Hk));
     * paged — the same value keys holding *pool* arrays (N, bs, Hk, D)
-      (scales (N, bs, Hk)), plus ``"block_table"`` (B, nb) int32.
+      (scales (N, bs, Hk)), plus ``"block_table"`` (B, nb) int32; bf16
+      pools may be stacked over layers, (L, N, bs, Hk, D), attended at
+      ``layer`` (traced; the flash kernel prefetches it, the ref and
+      dense paths index it before their gather).
 
     ``layout.impl`` selects ref oracle / dense XLA einsum / Pallas flash
     kernel; ``layout.window`` / ``layout.ring`` the masking variant (int8
@@ -157,22 +162,24 @@ def _decode_attention_jit(q, cache, lengths, *, layout, softmax_scale=None,
             return _decode.flash_decode_attention_paged_quant(
                 q, *args, table, lengths, softmax_scale=softmax_scale,
                 interpret=interp, q_lens=q_lens)
+        if layout.impl == "flash":
+            return _decode.flash_decode_attention_paged(
+                q, cache["k"], cache["v"], table, lengths, layer=layer,
+                window=layout.window, ring=layout.ring,
+                softmax_scale=softmax_scale, interpret=interp,
+                q_lens=q_lens)
+        k_pool, v_pool = (ref.pool_layer(cache[n], layer) for n in "kv")
         if layout.impl == "ref":
             return ref.decode_attention_paged(
-                q, cache["k"], cache["v"], table, lengths,
+                q, k_pool, v_pool, table, lengths,
                 window=layout.window, ring=layout.ring,
                 softmax_scale=softmax_scale, q_lens=q_lens)
-        if layout.impl == "dense":
-            from repro.models import attention
-            return attention.decode_attention(
-                q, ref.paged_gather(cache["k"], table),
-                ref.paged_gather(cache["v"], table), lengths,
-                window=layout.window, ring=layout.ring,
-                softmax_scale=softmax_scale, impl="dense", q_lens=q_lens)
-        return _decode.flash_decode_attention_paged(
-            q, cache["k"], cache["v"], table, lengths, window=layout.window,
-            ring=layout.ring, softmax_scale=softmax_scale, interpret=interp,
-            q_lens=q_lens)
+        from repro.models import attention
+        return attention.decode_attention(
+            q, ref.paged_gather(k_pool, table),
+            ref.paged_gather(v_pool, table), lengths,
+            window=layout.window, ring=layout.ring,
+            softmax_scale=softmax_scale, impl="dense", q_lens=q_lens)
     if layout.quantized:
         args = (cache["k_q"], cache["k_s"], cache["v_q"], cache["v_s"])
         if layout.impl == "ref":

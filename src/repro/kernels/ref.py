@@ -136,6 +136,12 @@ def paged_gather(pool, table):
     return pool[table.reshape(-1)].reshape((B, nb * bs) + pool.shape[2:])
 
 
+def pool_layer(pool, layer):
+    """Layer ``layer`` of a layer-stacked pool (L, N, bs, ...); a pool
+    without the layer axis (N, bs, ...) is the ``L = 1`` case."""
+    return pool if pool.ndim == 4 else pool[layer]
+
+
 def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths, *,
                            window=0, ring=False, softmax_scale=None,
                            q_lens=None):

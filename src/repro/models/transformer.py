@@ -132,22 +132,26 @@ def attn_decode(cfg: ArchConfig, p: Dict, x, position, ctx: ModelCtx,
 
 
 def attn_decode_paged(cfg: ArchConfig, p: Dict, x, position, ctx: ModelCtx,
-                      k_pool, v_pool, read_table, write_table, cache_len):
-    """One-token decode against a paged cache.  x (B,1,d); pools
-    (N, bs, Hk, D) shared across slots; tables (B, nb) int32; cache_len (B,).
+                      k_pool, v_pool, layer, read_table, write_table,
+                      cache_len):
+    """One-token decode of layer ``layer`` against a paged cache.  x
+    (B,1,d); pools (L, N, bs, Hk, D) stacked over layers and shared across
+    slots; tables (B, nb) int32; cache_len (B,).
 
-    The new K/V row lands at physical block ``write_table[b, len//bs]``,
-    row ``len % bs`` — the *write* table, so slots that do not own their
-    frontier block (shared prefix tails awaiting copy-on-write, or retired
-    slots with zeroed tables) scatter into the reserved null block 0
-    instead of corrupting a neighbour.  The engine guarantees every
-    *active* slot's frontier is exclusively owned (read == write) before
-    the step, so live tokens always land in readable rows.  Attention then
-    reads through the *read* table via the unified layout dispatch."""
+    The new K/V row lands at ``[layer, write_table[b, len//bs], len % bs]``
+    — the *write* table, so slots that do not own their frontier block
+    (shared prefix tails awaiting copy-on-write, or retired slots with
+    zeroed tables) scatter into the reserved null block 0 instead of
+    corrupting a neighbour.  The engine guarantees every *active* slot's
+    frontier is exclusively owned (read == write) before the step, so live
+    tokens always land in readable rows.  Attention then reads layer
+    ``layer`` through the *read* table via the unified layout dispatch.
+    The whole pools go in and come out: the write is an in-place row
+    scatter, never a copy of a layer."""
     from repro.cache_layout import CacheLayout
     from repro.kernels import ops
     B = x.shape[0]
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     S = read_table.shape[1] * bs                 # virtual position space
     h = layers.apply_norm(cfg, p["norm"], x)
     q, k, v = _qkv(cfg, p, h,
@@ -156,13 +160,13 @@ def attn_decode_paged(cfg: ArchConfig, p: Dict, x, position, ctx: ModelCtx,
     blk = cache_len // bs
     off = cache_len % bs
     phys = write_table[jnp.arange(B), blk]
-    k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
+    k_pool = k_pool.at[layer, phys, off].set(k[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, phys, off].set(v[:, 0].astype(v_pool.dtype))
     layout = CacheLayout(kind="paged", impl=ctx.decode_impl, block_size=bs)
     valid = jnp.minimum(cache_len + 1, S)
     o = ops.decode_attention(q, {"k": k_pool, "v": v_pool,
                                  "block_table": read_table}, valid,
-                             layout=layout)
+                             layout=layout, layer=layer)
     out = o.reshape(B, 1, cfg.q_dim) @ p["wo"]
     return out, k_pool, v_pool
 
@@ -223,20 +227,21 @@ def attn_decode_spec(cfg: ArchConfig, p: Dict, x, position, ctx: ModelCtx,
 
 
 def attn_decode_paged_spec(cfg: ArchConfig, p: Dict, x, position,
-                           ctx: ModelCtx, k_pool, v_pool, read_table,
+                           ctx: ModelCtx, k_pool, v_pool, layer, read_table,
                            write_table, cache_len, q_lens):
-    """Speculative k-row twin of :func:`attn_decode_paged`: the k-token
-    span scatters through the write table (row ``j`` at physical block
-    ``write_table[b, (len + j) // bs]``, offset ``(len + j) % bs``); rows
-    overflowing the virtual space land in the null block 0.  The engine
-    pre-owns every block the live span touches
+    """Speculative k-row twin of :func:`attn_decode_paged` (layer
+    ``layer`` of the stacked pools): the k-token span scatters through the
+    write table (row ``j`` at physical block ``write_table[b, (len + j) //
+    bs]``, offset ``(len + j) % bs``); rows overflowing the virtual space
+    land in the null block 0.  The engine pre-owns every block the live
+    span touches
     (:meth:`~repro.serving.block_pool.SlotTables.ensure_writable_span`),
     so accepted rows always land in readable blocks; rejected rows leave
     garbage at dead positions only."""
     from repro.cache_layout import CacheLayout
     from repro.kernels import ops
     B, Sq = x.shape[:2]
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nb = read_table.shape[1]
     S = nb * bs
     h = layers.apply_norm(cfg, p["norm"], x)
@@ -246,13 +251,13 @@ def attn_decode_paged_spec(cfg: ArchConfig, p: Dict, x, position,
     phys = write_table[jnp.arange(B)[:, None], blk]
     phys = jnp.where(pos < S, phys, 0)
     off = pos % bs
-    k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
+    k_pool = k_pool.at[layer, phys, off].set(k.astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, phys, off].set(v.astype(v_pool.dtype))
     layout = CacheLayout(kind="paged", impl=ctx.decode_impl, block_size=bs)
     o = ops.decode_attention(q, {"k": k_pool, "v": v_pool,
                                  "block_table": read_table},
                              jnp.minimum(cache_len + 1, S), layout=layout,
-                             q_lens=q_lens)
+                             q_lens=q_lens, layer=layer)
     out = o.reshape(B, Sq, cfg.q_dim) @ p["wo"]
     return out, k_pool, v_pool
 
@@ -473,24 +478,35 @@ def _uniform_decode(cfg, params, h, position, ctx, cache):
     return h, {"k": kcs, "v": vcs, "len": cache["len"] + 1}
 
 
-def _uniform_decode_paged(cfg, params, h, position, ctx, cache):
-    read_t = cache["block_table"]
-    write_t = cache["write_table"]
-
-    def body(x, inp):
-        blk, kp, vp = inp
-        a_out, kp, vp = attn_decode_paged(cfg, blk["attn"], x, position, ctx,
-                                          kp, vp, read_t, write_t,
-                                          cache["len"])
+def _paged_layer_scan(cfg, params, h, ctx, cache, attend):
+    """The paged decode's layer scan: the stacked pools ride in the carry
+    with the residual stream, and ``attend(p, x, k_pool, v_pool, layer)``
+    writes layer ``layer``'s rows into them in place and attends that
+    layer.  The pools are never scanned over as ``xs`` / ``ys``, which
+    would slice each layer out and stack it back (whole-pool copies every
+    step)."""
+    def body(carry, inp):
+        x, kp, vp = carry
+        blk, layer = inp
+        a_out, kp, vp = attend(blk["attn"], x, kp, vp, layer)
         x = x + a_out
         f_out, _ = ffn_apply(cfg, blk["ffn"], x, ctx)
-        x = x + f_out
-        return x, (kp, vp)
+        return (x + f_out, kp, vp), None
 
-    h, (kps, vps) = jax.lax.scan(body, h, (params["blocks"],
-                                           cache["k"], cache["v"]))
-    return h, {"k": kps, "v": vps, "block_table": read_t,
-               "write_table": write_t, "len": cache["len"] + 1}
+    (h, kp, vp), _ = jax.lax.scan(
+        body, (h, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cfg.num_layers)))
+    return h, {**cache, "k": kp, "v": vp}
+
+
+def _uniform_decode_paged(cfg, params, h, position, ctx, cache):
+    def attend(p, x, kp, vp, layer):
+        return attn_decode_paged(cfg, p, x, position, ctx, kp, vp, layer,
+                                 cache["block_table"], cache["write_table"],
+                                 cache["len"])
+
+    h, cache = _paged_layer_scan(cfg, params, h, ctx, cache, attend)
+    return h, {**cache, "len": cache["len"] + 1}
 
 
 def _uniform_decode_spec(cfg, params, h, position, ctx, cache, q_lens):
@@ -509,23 +525,12 @@ def _uniform_decode_spec(cfg, params, h, position, ctx, cache, q_lens):
 
 
 def _uniform_decode_paged_spec(cfg, params, h, position, ctx, cache, q_lens):
-    read_t = cache["block_table"]
-    write_t = cache["write_table"]
+    def attend(p, x, kp, vp, layer):
+        return attn_decode_paged_spec(
+            cfg, p, x, position, ctx, kp, vp, layer, cache["block_table"],
+            cache["write_table"], cache["len"], q_lens)
 
-    def body(x, inp):
-        blk, kp, vp = inp
-        a_out, kp, vp = attn_decode_paged_spec(
-            cfg, blk["attn"], x, position, ctx, kp, vp, read_t, write_t,
-            cache["len"], q_lens)
-        x = x + a_out
-        f_out, _ = ffn_apply(cfg, blk["ffn"], x, ctx)
-        x = x + f_out
-        return x, (kp, vp)
-
-    h, (kps, vps) = jax.lax.scan(body, h, (params["blocks"],
-                                           cache["k"], cache["v"]))
-    return h, {"k": kps, "v": vps, "block_table": read_t,
-               "write_table": write_t, "len": cache["len"]}
+    return _paged_layer_scan(cfg, params, h, ctx, cache, attend)
 
 
 # --- rwkv forward ------------------------------------------------------------

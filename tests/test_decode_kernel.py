@@ -252,6 +252,38 @@ def test_paged_flash_decode_window_and_ring(window, ring):
     assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
+N_LAYERS = 3
+
+
+@pytest.mark.parametrize("impl", ["ref", "dense", "flash"])
+@pytest.mark.parametrize("layer", range(N_LAYERS))
+def test_paged_decode_reads_one_layer_of_a_stacked_pool(layer, impl):
+    """A layer-stacked pool (L, N, bs, Hk, D) with a traced layer index
+    attends exactly that layer: it matches the oracle on the layer's own
+    4-D pool, and the flash kernel given that 4-D pool (the L = 1 case)
+    gives the same bits."""
+    from repro.cache_layout import CacheLayout
+    from repro.kernels import decode_attention as dk
+    bs = 8
+    q = _qkv_cache(seed=30)[0]
+    pools = [_paged_from_dense(*_qkv_cache(seed=31 + i)[1:], bs)
+             for i in range(N_LAYERS)]
+    tables = pools[0][2]
+    kp = jnp.stack([p[0] for p in pools])
+    vp = jnp.stack([p[1] for p in pools])
+    lens = jnp.asarray([5, 0, 40, S], jnp.int32)
+    lay = CacheLayout(kind="paged", impl=impl, block_size=bs)
+    out = jax.jit(lambda kp, vp, l: ops.decode_attention(
+        q, {"k": kp, "v": vp, "block_table": tables}, lens, layout=lay,
+        layer=l))(kp, vp, jnp.int32(layer))
+    want = ref.decode_attention_paged(q, kp[layer], vp[layer], tables, lens)
+    assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+    if impl == "flash":
+        one = dk.flash_decode_attention_paged(q, kp[layer], vp[layer],
+                                              tables, lens, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
+
+
 @pytest.mark.parametrize("lengths", RAGGED)
 def test_paged_flash_decode_quant_matches_dense(lengths):
     q, k, v = _qkv_cache(seed=10)

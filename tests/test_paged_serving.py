@@ -120,6 +120,70 @@ def test_decode_step_donates_the_slot_state():
     assert not any(x.is_deleted() for x in jax.tree.leaves(new_state))
 
 
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_paged_decode_step_is_the_dense_step_in_place(impl):
+    """One native paged decode step against the dense-layout step on the
+    same rows: for every slot that owns its frontier, the logits and the
+    pools (read through the tables) are bit-identical.  Nothing else in
+    the pools moves but each layer's new rows and the null block: a slot
+    whose write entry is the null block (a shared tail awaiting
+    copy-on-write) writes there and leaves its readable blocks as they
+    were."""
+    import jax.numpy as jnp
+    from repro.kernels import ref
+    cfg = reduced(get_arch("olmo-1b"))
+    params = tf.init_params(jax.random.PRNGKey(0), cfg)
+    ctx = tf.ModelCtx(decode_impl=impl, decode_block_k=PAGED.block_size)
+    bs, n_slots, max_len = PAGED.block_size, 4, 64
+    L, nb = cfg.num_layers, max_len // bs
+    lens = np.array([5, 17, 12, 33], np.int32)
+    owners, shared = [0, 1, 3], 2                  # slot 2: tail not owned
+    shape = (L, n_slots, max_len, cfg.num_kv_heads, cfg.head_dim)
+    kd, vd = (jax.random.normal(jax.random.PRNGKey(s), shape
+                                ).astype(cfg.dtype) for s in (1, 2))
+    rng = np.random.default_rng(3)
+    n_blocks = 1 + n_slots * nb + 5                # null + slots + spare
+    table = rng.permutation(np.arange(1, n_blocks))[:n_slots * nb]
+    table = table.reshape(n_slots, nb).astype(np.int32)
+    write = table.copy()
+    write[shared, lens[shared] // bs] = NULL_BLOCK
+
+    def pool(dense):
+        garbage = jax.random.normal(jax.random.PRNGKey(4), (L, n_blocks, bs)
+                                    + shape[3:]).astype(cfg.dtype)
+        return garbage.at[:, table.reshape(-1)].set(
+            dense.reshape((L, n_slots * nb, bs) + shape[3:]))
+
+    tokens = jnp.asarray(rng.integers(3, cfg.vocab_size, (n_slots, 1)),
+                         jnp.int32)
+    dense = {"k": kd, "v": vd, "len": jnp.asarray(lens)}
+    paged = {"k": pool(kd), "v": pool(vd), "block_table": jnp.asarray(table),
+             "write_table": jnp.asarray(write), "len": jnp.asarray(lens)}
+    step = jax.jit(lambda c, t: tf.decode_step(cfg, params, c, t, ctx))
+    want_logits, want = step(dense, tokens)
+    logits, got = step(paged, tokens)
+    np.testing.assert_array_equal(np.asarray(logits)[owners],
+                                  np.asarray(want_logits)[owners])
+    assert (np.asarray(got["len"]) == lens + 1).all()
+    touched = np.zeros((L, n_blocks, bs), bool)
+    touched[:, NULL_BLOCK] = True
+    for b in owners:
+        touched[:, table[b, lens[b] // bs], lens[b] % bs] = True
+    for n in "kv":
+        new, old = np.asarray(got[n]), np.asarray(paged[n])
+        for layer in range(L):
+            seen = np.asarray(ref.paged_gather(got[n][layer],
+                                               jnp.asarray(table)))
+            np.testing.assert_array_equal(seen[owners],
+                                          np.asarray(want[n])[layer, owners])
+        np.testing.assert_array_equal(new[~touched], old[~touched])
+        # layer 0's rows come before any attention: the shared slot's row
+        # is the dense step's, and it went to the null block
+        np.testing.assert_array_equal(
+            new[0, NULL_BLOCK, lens[shared] % bs],
+            np.asarray(want[n])[0, shared, lens[shared]])
+
+
 def test_paged_slots_pages_only_linear_kv_leaves():
     """The generic composition pools exactly the append-at-len KV leaves:
     gemma's window-bounded rings and whisper's cross-KV stay slot-resident;

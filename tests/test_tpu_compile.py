@@ -179,3 +179,39 @@ def test_olmo_decode_step_fits_one_chip(chip, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, total
+
+
+def test_paged_decode_step_updates_the_pool_in_place(chip, monkeypatch):
+    """The serve deployment's paged olmo-1b decode step (64 slots x 512,
+    2,048 blocks of 16, flash decode, slot state donated) writes its new
+    K/V rows into the stacked pools in place: no temporary near a pool's
+    2.15 GB, and no copy or (dynamic-)slice that materialises a pool, one
+    layer of it, or the kernel's (L*N, ...) view of it."""
+    import re
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_arch("olmo-1b")
+    ctx = tf.ModelCtx(decode_impl="flash")
+    on_chip = lambda t: jax.tree.map(                  # noqa: E731
+        lambda x: chip(x.shape, x.dtype), t)
+    params = on_chip(jax.eval_shape(
+        lambda: tf.init_params(jax.random.PRNGKey(0), cfg)))
+    state = on_chip(jax.eval_shape(lambda: tf.init_paged_slots(
+        cfg, 64, 512, num_blocks=2048, block_size=16)))
+    compiled = jax.jit(
+        lambda p, c, t: tf.decode_step(cfg, p, c, t, ctx),
+        donate_argnums=(1,)).lower(params, state,
+                                   chip((64, 1), I32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    shape = r"= bf16\[(?:(?:16,)?2048|32768),16,16,128\]\{[^}]*\} "
+    pool = re.compile(shape + r"(copy|dynamic-slice|dynamic-update-slice)\(")
+    fusion = re.compile(shape + r"fusion\([^\n]*calls=%([\w.-]+)")
+    moving = [m.group(0) for m in pool.finditer(text)]
+    for m in fusion.finditer(text):
+        body = text[text.index(f"%{m.group(1)} "):]
+        body = body[:body.index("\n}")]
+        if re.search(r"\b(copy|dynamic-slice|dynamic-update-slice)\(", body):
+            moving.append(m.group(0))
+    assert not moving, moving
