@@ -23,22 +23,12 @@ position it reads the gap of the token it ranks first.
 """
 from __future__ import annotations
 
-import importlib.util
 from typing import Dict, List
 
 import numpy as np
 
 from benchlib import gen
-from benchlib.spec import BENCH_DIR
-
-
-def load_reference(c: dict):
-    path = BENCH_DIR / "configs" / f"{c['reference']}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_ref_{c['reference']}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from benchlib.spec import load_reference
 
 
 def sample_requests(win, seed: int, k: int) -> List[int]:

@@ -5,21 +5,16 @@ from __future__ import annotations
 
 import dataclasses
 
+from benchlib.spec import load_reference
+
 
 def program_config(c: dict):
     """The program's registered architecture for this configuration,
-    checked against every size the file states."""
+    checked against every field the configuration's module derives from
+    the file (``program_fields``)."""
     from repro.config import get_arch
     cfg = get_arch(c["program_arch"])
-    want = {"num_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
-            "num_heads": c["num_attention_heads"],
-            "num_kv_heads": c["num_key_value_heads"],
-            "head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
-            "vocab_size": c["vocab_size"], "rope_theta": c["rope_theta"],
-            "tie_embeddings": c["tie_word_embeddings"], "dtype": c["dtype"],
-            "vocab_pad_to": c["vocab_pad_to"],
-            "norm_type": {"nonparam_layernorm": "nonparam_ln",
-                          "rmsnorm": "rmsnorm"}[c["norm"]]}
+    want = load_reference(c).program_fields(c)
     if c.get("program_overrides"):
         cfg = dataclasses.replace(cfg, **c["program_overrides"])
     got = {k: getattr(cfg, k) for k in want}

@@ -2,13 +2,17 @@
 
 A cell names a configuration and a traffic mix; each is a file found by
 its name (``configs/<config>.json``, ``traffic/<traffic>.json``), and each
-per-layer metric is a reader ``metrics/<name>.py``.  Adding a cell, a
+per-layer metric is a reader ``metrics/<name>.py``.  A configuration names
+its own module under ``reference`` (a path from the repository's root):
+its plain reference, and everything that follows its layer layout, the
+weights, the program's fields and the costs.  Adding a cell, a
 configuration, a mix or a metric is adding files and entries: nothing
 here changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -71,17 +75,33 @@ def _load_json(path: pathlib.Path, what: str) -> Dict:
         return json.load(f)
 
 
-def load_reader(path: pathlib.Path) -> Callable:
-    """A per-layer metric's reader: ``read(run) -> float | None``."""
+def _exec(path: pathlib.Path, prefix: str, what: str):
     if not path.is_file():
-        raise SpecError(f"metric reader {path} not found")
+        raise SpecError(f"{what} {path} not found")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", path.stem), path)
+        prefix + re.sub(r"\W", "_", path.stem), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+def load_reader(path: pathlib.Path) -> Callable:
+    """A per-layer metric's reader: ``read(run) -> float | None``."""
+    mod = _exec(path, "bench_metric_", "metric reader")
     if not callable(getattr(mod, "read", None)):
         raise SpecError(f"{path} defines no read(run)")
     return mod.read
+
+
+def load_reference(c: dict):
+    """The configuration's own module: the file its ``reference`` names,
+    relative to the repository's root, loaded once a process."""
+    return _module(ROOT / c["reference"])
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: pathlib.Path):
+    return _exec(path, "bench_ref_", "configuration module")
 
 
 def _metric(entry: Dict, per_layer: bool) -> Metric:

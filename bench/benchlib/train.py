@@ -12,6 +12,9 @@ window then goes on with the same object.
 
 The reference follows the same three steps in float32 at
 ``Precision.HIGHEST``, in blocks of rows, with AdamW written out plainly.
+On several chips its state is spread over the cell's own chips, each leaf
+split along one axis (``reference_placement``): plain ``jax.numpy`` still,
+nothing of the program's plan, and it fits where one chip would not.
 """
 from __future__ import annotations
 
@@ -41,6 +44,7 @@ class Train:
     feed: object
     place: object
     psh: object
+    devices: List = dataclasses.field(default_factory=list)
     batches: List[Dict[str, np.ndarray]] = dataclasses.field(
         default_factory=list)
 
@@ -88,7 +92,8 @@ def build(c: dict, job: dict, seed: int) -> Train:
         yield first
         yield from feed
 
-    return Train(cfg, c, job, seed, fn, params, opt, rows(), place, psh)
+    return Train(cfg, c, job, seed, fn, params, opt, rows(), place, psh,
+                 list(mesh.devices.flat))
 
 
 def _leaf_norms(tree) -> Dict[str, float]:
@@ -239,27 +244,58 @@ def _schedule(step: int, job: dict) -> float:
     return base * (0.1 + 0.9 * 0.5 * (1 + np.cos(np.pi * prog)))
 
 
+def reference_placement(c: dict, devices) -> Optional[object]:
+    """Where the reference keeps its state on several chips: every leaf
+    over a 1-D mesh of ``devices``, split along its largest axis that
+    their number divides (the last of equals), whole on each where none
+    does.  None on one chip: the default device, as ever."""
+    if not devices or len(devices) < 2:
+        return None
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    n = len(devices)
+    mesh = Mesh(np.asarray(devices), ("ref",))
+
+    def one(x):
+        axes = [i for i, k in enumerate(x.shape) if k % n == 0]
+        if not axes:
+            return NamedSharding(mesh, P())
+        ax = max(axes, key=lambda i: (x.shape[i], i))
+        return NamedSharding(mesh, P(*(["ref" if i == ax else None
+                                        for i in range(x.ndim)])))
+    return jax.tree.map(one, weights.shapes(c))
+
+
 def reference_steps(c: dict, job: dict, params, batches, dtype=None,
-                    quant: bool = False) -> Dict:
+                    quant: bool = False, placement=None) -> Dict:
     """The three steps of mixed-precision AdamW as the configuration
     states it: float32 master weights, the forward and backward at the
     master rounded to the weights' dtype (bfloat16), bias-corrected
     moments, decoupled weight decay, gradients clipped to a global norm,
-    on the mean cross entropy, summed over blocks of rows."""
+    on the mean cross entropy, summed over blocks of rows.  ``params``
+    lie as ``placement`` (:func:`reference_placement`) says, and so does
+    every tree of state and gradients made from them."""
     import jax
     import jax.numpy as jnp
-    from benchlib.check import load_reference
+    from benchlib.spec import load_reference
     ref = load_reference(c)
     dtype = dtype or jnp.float32
     blk = job["check_rows"]
 
-    @jax.jit
     def block_grad(w, tokens, targets, mask):
         def f(w):
             return ref.loss(c, w, {"tokens": tokens, "targets": targets,
                                    "mask": mask}, dtype=dtype, quant=quant)
         (s, n), g = jax.value_and_grad(f, has_aux=True)(w)
         return s, n, g
+
+    if placement is None:
+        block_grad = jax.jit(block_grad)
+    else:
+        whole = jax.sharding.NamedSharding(
+            jax.tree.leaves(placement)[0].mesh, jax.sharding.PartitionSpec())
+        block_grad = jax.jit(block_grad,
+                             out_shardings=(whole, whole, placement))
 
     w = jax.tree.map(lambda x: x.astype(jnp.float32), params)
     m = jax.tree.map(jnp.zeros_like, w)
@@ -334,43 +370,44 @@ def check_numbers(t: Train, prog: Dict) -> Dict[str, float]:
     import jax
     t.params = t.opt = None
     gc.collect()
-    params = weights.make_params(t.c, t.seed)
-    ref = reference_steps(t.c, t.job, params, t.batches)
+    where = reference_placement(t.c, t.devices)
+    params = weights.make_params(t.c, t.seed, out_shardings=where)
+    ref = reference_steps(t.c, t.job, params, t.batches, placement=where)
     del params
     jax.block_until_ready(0)
+    print(f"losses: program {prog['loss']} reference {ref['loss']}",
+          file=sys.stderr)
     return compare(prog, ref, t.job["dead_leaf_floor"])
 
 
-def control_numbers(c: dict, job: dict, seed: int, batches) -> Dict:
-    """The control: the reference with int8 matmuls (weights per output
-    channel, activations per row) in the program's place, against the
-    reference at float32."""
-    import jax.numpy as jnp
-    params = weights.make_params(c, seed)
-    ref = reference_steps(c, job, params, batches)
-    low = reference_steps(c, job, params, batches, dtype=jnp.bfloat16,
-                          quant=True)
-    return compare(low, ref, job["dead_leaf_floor"])
-
-
-def fault_numbers(c: dict, job: dict, seed: int, batches) -> Dict:
-    """What the faults a one-chip step can have read against the
+def fault_numbers(c: dict, job: dict, seed: int, batches,
+                  devices=None) -> Dict:
+    """What the control and the faults a step can have read against the
     reference, each planted in the reference put in the program's place:
-    a step that leaves out half its batch (the reference on the first half
-    of each batch's rows, the mean over them), and a step that returns its
+    the control (the reference with int8 matmuls, weights per output
+    channel and activations per row, at bfloat16), a step that leaves out half its batch (the reference on the
+    first half of each batch's rows, the mean over them; on two data
+    replicas this is also what the first reads when the exchange of
+    gradients between them is left out), and a step that returns its
     state unchanged (the optimizer's state stays zero, so the gradient
     read back and the change are zero, and every step's loss is taken at
     the first weights)."""
-    params = weights.make_params(c, seed)
-    ref = reference_steps(c, job, params, batches)
+    import jax.numpy as jnp
+    where = reference_placement(c, devices)
+    params = weights.make_params(c, seed, out_shardings=where)
+    ref = reference_steps(c, job, params, batches, placement=where)
+    low = reference_steps(c, job, params, batches, dtype=jnp.bfloat16,
+                          quant=True, placement=where)
     half = [{k: v[: v.shape[0] // 2] for k, v in b.items()}
             for b in batches]
     still = reference_steps(c, {**job, "learning_rate": 0.0}, params,
-                            batches)
+                            batches, placement=where)
     still["grad"] = {k: 0.0 for k in still["grad"]}
     still["grad_vec"] = {k: 0.0 * v for k, v in still["grad_vec"].items()}
     still["change"] = {k: 0.0 for k in still["change"]}
     floor = job["dead_leaf_floor"]
-    return {"half_batch": compare(reference_steps(c, job, params, half),
+    return {"control": compare(low, ref, floor),
+            "half_batch": compare(reference_steps(c, job, params, half,
+                                                  placement=where),
                                   ref, floor),
             "state_unchanged": compare(still, ref, floor)}

@@ -1,4 +1,7 @@
-"""Plain reference of the dense decoders (olmo-1b, recllm-base).
+"""The dense decoders (olmo-1b, recllm-base): their plain reference, and
+what follows from their layer layout (the benchmark's weights in the
+program's layout, the program's fields, and the operations and bytes of
+each call).
 
 Pre-norm decoder: token embedding, then per layer
 ``x += Wo attn(rope(norm(x) Wq), rope(norm(x) Wk), norm(x) Wv)`` with a
@@ -23,9 +26,12 @@ activations per row), as an int8 matmul path would.
 from __future__ import annotations
 
 import functools
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+
+from benchlib.weights import padded_vocab
 
 HIGHEST = jax.lax.Precision.HIGHEST
 KEYS = ("num_attention_heads", "num_key_value_heads", "head_dim", "norm",
@@ -156,3 +162,117 @@ def loss(c: dict, w: dict, batch: dict, dtype=jnp.float32, quant=False):
     gold = jnp.take_along_axis(lg, batch["targets"][..., None], -1)[..., 0]
     mask = batch["mask"]
     return jnp.sum((lse - gold) * mask), jnp.sum(mask)
+
+
+# -- the layout: weights, the program's fields, costs ------------------------
+
+def _norm(key, c, d):
+    if c["norm"] == "nonparam_layernorm":
+        return {}
+    return {"scale": 0.1 * jax.random.normal(key, (d,), jnp.float32)}
+
+
+def _dense(key, shape, dtype):
+    w = jax.random.normal(key, shape, jnp.float32) / jnp.sqrt(shape[-2])
+    return w.astype(dtype)
+
+
+def init_params(c: dict, key):
+    """The weights in the program's layout, every leaf from ``key``:
+    normal(0, 1/sqrt(fan_in)) projections, a normal(0, 0.02) embedding
+    whose padding rows past ``vocab_size`` are zero (rows no token ever
+    trains), and ``(1 + scale)`` norm weights with scale normal(0, 0.1)
+    where the norm has one; bf16 where the configuration serves bf16."""
+    dt = jnp.dtype(c["dtype"])
+    L, d = c["num_hidden_layers"], c["hidden_size"]
+    q = c["num_attention_heads"] * c["head_dim"]
+    kv = c["num_key_value_heads"] * c["head_dim"]
+    f = c["intermediate_size"]
+    V = padded_vocab(c)
+    ks = iter(jax.random.split(key, 16))
+    emb = 0.02 * jax.random.normal(next(ks), (V, d), jnp.float32)
+    emb = jnp.where(jnp.arange(V)[:, None] < c["vocab_size"], emb, 0.0)
+    stack = lambda k, n: jax.vmap(lambda kk: _norm(kk, c, n))(  # noqa: E731
+        jax.random.split(k, L))
+    return {
+        "embed": emb.astype(dt),
+        "final_norm": _norm(next(ks), c, d),
+        "blocks": {
+            "attn": {"norm": stack(next(ks), d),
+                     "wq": _dense(next(ks), (L, d, q), dt),
+                     "wk": _dense(next(ks), (L, d, kv), dt),
+                     "wv": _dense(next(ks), (L, d, kv), dt),
+                     "wo": _dense(next(ks), (L, q, d), dt)},
+            "ffn": {"norm": stack(next(ks), d),
+                    "mlp": {"wi_gate": _dense(next(ks), (L, d, f), dt),
+                            "wi_up": _dense(next(ks), (L, d, f), dt),
+                            "wo": _dense(next(ks), (L, f, d), dt)}},
+        },
+    }
+
+
+def program_fields(c: dict) -> dict:
+    """The attributes the program's registered architecture must have."""
+    return {"num_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
+            "num_heads": c["num_attention_heads"],
+            "num_kv_heads": c["num_key_value_heads"],
+            "head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
+            "vocab_size": c["vocab_size"], "rope_theta": c["rope_theta"],
+            "tie_embeddings": c["tie_word_embeddings"], "dtype": c["dtype"],
+            "vocab_pad_to": c["vocab_pad_to"],
+            "norm_type": {"nonparam_layernorm": "nonparam_ln",
+                          "rmsnorm": "rmsnorm"}[c["norm"]]}
+
+
+def layer_matmul_params(c: dict) -> int:
+    d, f = c["hidden_size"], c["intermediate_size"]
+    q = c["num_attention_heads"] * c["head_dim"]
+    kv = c["num_key_value_heads"] * c["head_dim"]
+    return c["num_hidden_layers"] * (2 * d * q + 2 * d * kv + 3 * d * f)
+
+
+def head_params(c: dict) -> int:
+    return padded_vocab(c) * c["hidden_size"]
+
+
+def _qdim(c: dict) -> int:
+    return c["num_attention_heads"] * c["head_dim"]
+
+
+def prefill_flops(c: dict, n: int) -> float:
+    """A prompt of ``n`` tokens: every layer at every position, causal
+    attention over n(n+1)/2 pairs, the LM head at the last position."""
+    L = c["num_hidden_layers"]
+    return (2.0 * layer_matmul_params(c) * n
+            + 2.0 * L * _qdim(c) * n * (n + 1)
+            + 2.0 * head_params(c))
+
+
+def decode_flops(c: dict, rows: Sequence[int]) -> float:
+    """One decode step; ``rows[i]`` is the context slot i attends,
+    its new token included."""
+    L = c["num_hidden_layers"]
+    per_tok = 2.0 * (layer_matmul_params(c) + head_params(c))
+    return per_tok * len(rows) + 4.0 * L * _qdim(c) * float(sum(rows))
+
+
+def decode_attn_bytes(c: dict, rows: Sequence[int],
+                      kv_bytes: int = 2) -> float:
+    """Bytes one decode step's attention must move over all layers: the
+    K and V rows of each live prefix, each query read and output written."""
+    L = c["num_hidden_layers"]
+    Hk, D = c["num_key_value_heads"], c["head_dim"]
+    kv = 2.0 * Hk * D * kv_bytes * float(sum(rows))
+    qo = 2.0 * _qdim(c) * 2 * len(rows)
+    return L * (kv + qo)
+
+
+def train_flops(c: dict, batch: int, seq: int) -> float:
+    """Forward and backward of one step (3x the forward), recomputation
+    not counted: matmuls, the LM head at every position, causal
+    attention."""
+    L = c["num_hidden_layers"]
+    fwd = (2.0 * (layer_matmul_params(c) + head_params(c))
+           * batch * seq
+           + 2.0 * L * _qdim(c) * seq * (seq + 1) * batch)
+    return 3.0 * fwd

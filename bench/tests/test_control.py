@@ -46,5 +46,5 @@ def test_train_control_is_not():
     c, job = cell.config, cell.traffic
     feed = gen.train_rows(job, SEED, c["vocab_size"], job["batch"])
     batches = [next(feed) for _ in range(3)]
-    nums = train.control_numbers(c, job, SEED, batches)
+    nums = train.fault_numbers(c, job, SEED, batches)["control"]
     assert not _limits_met(nums, job["limits"]), nums

@@ -5,12 +5,13 @@ real harness, on the CPU at a small size, against the cell's own limits.
 Serving: a token altered where the engine produces it, and a CF answer
 altered where the head produces it.  Training on one chip: a step that
 returns its state unchanged, and a step that leaves out half of its
-batch and takes the mean over the rest.  (The exchange between chips is
-a fault only a four-chip cell can have.)"""
+batch and takes the mean over the rest (``bench/tools/faults.py``).  The
+exchange between chips is a fault only a four-chip cell can have:
+``test_multichip.py``."""
+import importlib.util
 import json
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 import tiny
@@ -48,26 +49,22 @@ def _cf_answer_altered(monkeypatch):
     monkeypatch.setattr(cf_head.CFHead, "score", bad)
 
 
-def _state_unchanged(monkeypatch):
-    from repro.optimizer import adamw
-    monkeypatch.setattr(adamw, "adamw_apply",
-                        lambda params, grads, opt, *a, **kw: (params, opt))
+def tool(name: str):
+    """A module of ``bench/tools``, loaded from its file."""
+    path = tiny.spec.BENCH_DIR / "tools" / f"{name}.py"
+    sp = importlib.util.spec_from_file_location(f"bench_tool_{name}", path)
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod
 
 
-def _half_batch(monkeypatch):
-    from repro.models import transformer as tf
-    loss_fn = tf.loss_fn
-
-    def half(cfg, params, batch, *a, **kw):
-        n = batch["tokens"].shape[0] // 2
-        return loss_fn(cfg, params, jax.tree.map(lambda x: x[:n], batch),
-                       *a, **kw)
-    monkeypatch.setattr(tf, "loss_fn", half)
+FAULTS = tool("faults")
 
 
 @pytest.mark.parametrize("kind,fault", [
     ("serve", _token_altered), ("serve", _cf_answer_altered),
-    ("train", _state_unchanged), ("train", _half_batch)])
+    ("train", FAULTS.state_unchanged), ("train", FAULTS.half_batch)],
+    ids=lambda v: v if isinstance(v, str) else "_" + v.__name__.lstrip("_"))
 def test_fault_is_not_correct(kind, fault, monkeypatch, capsys):
     fault(monkeypatch)
     res = run(kind, capsys)

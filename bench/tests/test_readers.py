@@ -64,3 +64,38 @@ def test_percentiles_in_ms():
     assert reader("gen.late_p95_ms")(r) == pytest.approx(95.0)
     r = run_with(counters={"cf.hits": 3, "cf.misses": 1})
     assert reader("cf.hit_rate")(r) == pytest.approx(75.0)
+
+
+def _collective_profile():
+    """Two chips and the host.  Each chip's async all-gather is issued,
+    computed behind (not counted) and waited for in its -done (counted);
+    a synchronous all-reduce and an all-gather inside a loop count whole."""
+    from test_program_spans import Ev, Line, Plane, Profile
+    chip0 = [Ev("fusion.1", 0, 100), Ev("async-collective-start.2", 100, 105),
+             Ev("fusion.3", 105, 300), Ev("async-collective-done.2", 300, 340),
+             Ev("all-reduce.4", 400, 460), Ev("fusion.5", 460, 500),
+             Ev("while.6", 500, 600), Ev("all-gather.7", 520, 540),
+             Ev("fusion.8", 540, 600)]
+    chip1 = [Ev("fusion.1", 0, 120), Ev("async-collective-start.2", 120, 125),
+             Ev("fusion.3", 125, 310), Ev("async-collective-done.2", 310, 320),
+             Ev("all-reduce.4", 400, 460), Ev("fusion.5", 460, 600)]
+    return Profile([Plane("/device:TPU:0", [Line("XLA Ops", chip0)]),
+                    Plane("/device:TPU:1", [Line("XLA Ops", chip1)]),
+                    Plane("/host:CPU", [Line("python",
+                                             [Ev("bench.feed", 0, 600)])])])
+
+
+def test_collective_exposed_share_counts_the_waits():
+    tr = trace.reduce_profile(_collective_profile())
+    r = run_with(trace=tr)
+    # chip 0 waits 40 + 60 + 20 ns, chip 1 10 + 60, in a 600 ns window
+    assert reader("train.collective_exposed_share")(r) == pytest.approx(
+        100.0 * (120 + 70) / 2 / 600)
+
+
+def test_collective_exposed_share_needs_two_chips():
+    tr = trace.Reduced(window_s=1.0, busy_s=0.5, chips=1,
+                       ops_s={"all-reduce": 0.1}, op_counts={"all-reduce": 1},
+                       gaps_s={})
+    assert reader("train.collective_exposed_share")(run_with(trace=tr)) \
+        is None

@@ -1,6 +1,9 @@
 """The trace reduction, on a small trace recorded on a TPU v5e
 (``bench/tools/record_trace.py``: a 1024^2 bf16 matmul and the paged
-flash-decode kernel, three times each under ``bench.*`` annotations)."""
+flash-decode kernel, three times each under ``bench.*`` annotations), and
+on one recorded on four (``bench/tools/record_collective_trace.py``: a
+step whose partial sums are all-reduced and whose rows are all-gathered,
+three times)."""
 import pathlib
 
 import pytest
@@ -8,6 +11,7 @@ import pytest
 from benchlib import trace
 
 DATA = pathlib.Path(__file__).parent / "data" / "small.xplane.pb"
+COLLECTIVES = DATA.parent / "collectives.xplane.pb"
 
 
 @pytest.fixture(scope="module")
@@ -69,3 +73,20 @@ def test_nested_operations_charge_their_own_time():
     assert ops == pytest.approx({"while": 50e-9, "fusion": 40e-9,
                                  "copy": 15e-9, "add": 5e-9})
     assert counts == {"while": 1, "fusion": 2, "copy": 1, "add": 1}
+
+
+def test_collectives_on_four_chips():
+    from benchlib import spec
+    from benchlib.runlog import Run
+    tr = trace.reduce_file(str(COLLECTIVES))
+    assert tr.chips == 4
+    assert tr.op_counts["all-reduce"] == tr.op_counts["all-gather"] == 3
+    run = Run(config={}, traffic={}, peaks={}, devices=[None] * 4)
+    run.trace = tr
+    share = spec.load_reader(spec.BENCH_DIR / "metrics"
+                             / "train.collective_exposed_share.py")(run)
+    # each chip waits on its all-reduce and all-gather, ~0.19 of 1.6 ms a
+    # step; the matmul, tanh and copies are not exchanges
+    waits = tr.ops_s["all-reduce"] + tr.ops_s["all-gather"]
+    assert share == pytest.approx(100.0 * waits / tr.window_s, rel=1e-12)
+    assert 0 < share < 100.0 * tr.busy_s / tr.window_s

@@ -36,18 +36,21 @@ def serve_mix() -> dict:
     return m
 
 
-def train_job() -> dict:
-    j = copy.deepcopy(_load(spec.BENCH_DIR / "traffic" / "packed-512.json"))
-    j.update(seq=32, batch=4, doc_log_mean=2.0, doc_max=32,
-             check_rows=2)
+def train_job(name: str = "packed-512") -> dict:
+    j = copy.deepcopy(_load(spec.BENCH_DIR / "traffic" / f"{name}.json"))
+    j.update(seq=32, batch=4 * j["data"], doc_log_mean=2.0, doc_max=32,
+             eos_id=2, check_rows=2)
     return j
+
+
+REAL = {"serve": "olmo1b.serve.history-cf", "train": "recllm.train.1chip",
+        "dp2tp2": "olmo1b.train.dp2tp2"}
 
 
 def cell(kind: str) -> spec.Cell:
     """A Cell with every metric the real cell of that kind reports."""
-    real = {"serve": "olmo1b.serve.history-cf",
-            "train": "recllm.train.1chip"}[kind]
-    c = spec.load_cell(real)
+    c = spec.load_cell(REAL[kind])
     c.config = config(c.config_name)
-    c.traffic = serve_mix() if kind == "serve" else train_job()
+    c.traffic = (serve_mix() if kind == "serve"
+                 else train_job(c.traffic_name))
     return c

@@ -13,7 +13,9 @@ the check's numbers for the program; for the first ``n_control`` seeds
 also the control's (the reference at the precision below the
 configuration's).  The limits in the traffic file are set from these;
 serving readings give the mean of each difference beside its widest, and
-training readings the faults planted in the reference.
+training readings the faults planted in the reference and, on a cell of
+several chips, the exchange between them left out of the program
+(``bench/tools/faults.py``), which needs a second build of the step.
 Each result is one JSON line on standard output.
 """
 import json
@@ -23,7 +25,7 @@ import time
 import traceback
 
 HERE = pathlib.Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(HERE), str(HERE.parent / "src")]
+sys.path[:0] = [str(HERE), str(HERE.parent / "src"), str(HERE / "tools")]
 
 
 def sweep(cell, seed, seconds, rates, counter):
@@ -101,10 +103,11 @@ def readings(cell, seconds, n_control, seeds, counter):
                 prog = train.check_numbers(t, prog_r)
                 ctrl = None
                 if i < n_control:
-                    ctrl = train.control_numbers(cell.config, cell.traffic, seed,
-                                                 t.batches)
-                    ctrl["faults"] = train.fault_numbers(
-                        cell.config, cell.traffic, seed, t.batches)
+                    ctrl = train.fault_numbers(cell.config, cell.traffic,
+                                               seed, t.batches, t.devices)
+                    if cell.chips > 1:
+                        ctrl["exchange_left_out"] = planted(
+                            cell, seed, "exchange_left_out")
                 del t
             print(json.dumps({"seed": seed, "setup_s": setup,
                               "program": prog, "control": ctrl, **extra}),
@@ -112,6 +115,21 @@ def readings(cell, seconds, n_control, seeds, counter):
         except Exception:
             traceback.print_exc()
             print(json.dumps({"seed": seed, "error": True}), flush=True)
+
+
+def planted(cell, seed, fault):
+    """The check's numbers of the program with ``fault`` planted in its
+    step, built anew, through its first three steps."""
+    import faults
+    import pytest
+    from benchlib import train
+    mp = pytest.MonkeyPatch()
+    try:
+        faults.TRAIN[fault](mp)
+        t = train.build(cell.config, cell.traffic, seed)
+        return train.check_numbers(t, train.first_steps(t))
+    finally:
+        mp.undo()
 
 
 def main(argv) -> int:

@@ -26,10 +26,10 @@ def main(config: str, job: str, batches) -> int:
     from jax.experimental import topologies
     from jax.sharding import Mesh
 
+    from benchlib import weights
     from benchlib.program import program_config
     from repro.config import ParallelConfig, ShapeConfig, TrainConfig
     from repro.core.hybrid import auto_plan
-    from repro.models import transformer as tf
     from repro.optimizer import adamw
     from repro.runtime import trainer
 
@@ -50,8 +50,7 @@ def main(config: str, job: str, batches) -> int:
             microbatches=j["microbatches"]))
         _, jitted, sh_for = trainer.make_hybrid_train_step(
             cfg, plan, TrainConfig(steps=j["schedule_steps"]))
-        ps = jax.eval_shape(lambda: tf.init_params(jax.random.PRNGKey(0),
-                                                   cfg))
+        ps = weights.shapes(c)
         os_ = jax.eval_shape(adamw.init_opt_state, ps)
         bs = {"tokens": jax.ShapeDtypeStruct((B, j["seq"]), np.int32),
               "targets": jax.ShapeDtypeStruct((B, j["seq"]), np.int32),
